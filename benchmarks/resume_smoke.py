@@ -1,19 +1,22 @@
 """Kill-and-resume smoke: a real SIGKILL mid-solve, then a bit-identical
 resume.
 
-The parent solves the instance uninterrupted in-process (the baseline),
-then launches a CHILD process running the same checkpointed solve
-(``--child``, ``checkpoint_every=1`` so every chunk boundary is durable),
-SIGKILLs it as soon as checkpoints appear on disk, and resumes from the
-survivors via :meth:`SolverSession.resume` — asserting the final result is
-bit-identical to the baseline (modulo wall-clock and the durability
-counters, which are outside the contract).
+Every solve runs in a child process, one at a time, and the parent never
+initializes a JAX backend: a chip belongs to one process, so a parent that
+had touched JAX would hold it while its children wait.  A ``baseline`` child
+solves the instance uninterrupted; a ``solve`` child runs the same
+checkpointed solve (``checkpoint_every=1`` so every chunk boundary is
+durable) and is SIGKILLed as soon as checkpoints appear on disk; a
+``resume`` child restores the survivors via :meth:`SolverSession.resume`.
+The parent asserts the resumed result is bit-identical to the baseline
+(modulo wall-clock and the durability counters, which are outside the
+contract).
 
-A double-kill cycle then SIGKILLs the RECOVERY itself: a second child
+A double-kill cycle then SIGKILLs the RECOVERY itself: a ``recover`` child
 resumes from the survivors while continuing to checkpoint into the same
 directory, is killed again once a newer generation is durable, and the
-final in-process resume must still be bit-identical — checkpoints written
-by a recovering process are as good as any other.
+final resume must still be bit-identical — checkpoints written by a
+recovering process are as good as any other.
 
 A further kill cycle runs the same contract MID-SPILL: a saturating
 ``frontier_spill`` solve whose checkpoints carry a non-empty cold tier —
@@ -23,7 +26,9 @@ survives a SIGKILL at any chunk boundary.
 
 Also records the §H durability overheads for EXPERIMENTS.md /
 benchmarks/out/RESUME_smoke.json: checkpoint write cost (checkpointed vs
-plain solve wall), on-disk checkpoint size, and resume latency.
+plain solve wall, both on a warm plane cache inside the baseline child),
+on-disk checkpoint size, and resume latency (the resume child's solve call,
+compile included).
 
 Usage:
   PYTHONPATH=src python -m benchmarks.resume_smoke           # full
@@ -42,12 +47,10 @@ import sys
 import tempfile
 import time
 
-import numpy as np
-
 OUT_DIR = os.path.join(os.path.dirname(__file__), "out")
 RESUME_JSON = os.path.join(OUT_DIR, "RESUME_smoke.json")
 
-# the one deterministic workload both processes build (seeded generator);
+# the one deterministic workload every process builds (seeded generator);
 # the spill variant pins a saturating capacity so checkpoints mid-solve
 # carry a non-empty cold tier
 def _workload(smoke: bool, spill: bool = False, deep: bool = False):
@@ -79,23 +82,112 @@ def _workload(smoke: bool, spill: bool = False, deep: bool = False):
     return g, cfg
 
 
-def _child(
-    ckpt_dir: str,
-    smoke: bool,
-    spill: bool = False,
-    resume: bool = False,
-    deep: bool = False,
-) -> None:
-    from repro.api import SolverSession
+def _baseline(smoke: bool) -> dict:
+    """The uninterrupted solves of all three workloads, plus the checkpoint
+    write cost of the main one (warm plane cache, so plain-vs-checkpointed
+    walls compare steady-state write cost, not a compile against a hit)."""
+    from repro.api import PlaneCache, SolverSession
+    from repro.checkpoint.store import latest_step
 
-    if resume:
-        # recovery child: resume from the survivors AND keep checkpointing
-        # into the same directory — so the parent can SIGKILL it again
-        # mid-recovery
+    g, cfg = _workload(smoke)
+    cache = PlaneCache()
+    SolverSession(config=cfg, cache=cache).solve(g)
+    t0 = time.perf_counter()
+    base = SolverSession(config=cfg, cache=cache).solve(g)
+    plain_wall = time.perf_counter() - t0
+
+    d_cost = tempfile.mkdtemp(prefix="resume_smoke_cost_")
+    try:
+        t0 = time.perf_counter()
+        ck_run = SolverSession(config=cfg, cache=cache).solve(
+            g, checkpoint_dir=d_cost
+        )
+        ckpt_wall = time.perf_counter() - t0
+        ckpt_bytes = _dir_bytes(
+            os.path.join(d_cost, f"step_{latest_step(d_cost)}")
+        )
+    finally:
+        shutil.rmtree(d_cost, ignore_errors=True)
+
+    g_dp, cfg_dp = _workload(smoke, deep=True)
+    g_sp, cfg_sp = _workload(smoke, spill=True)
+    return dict(
+        main=base.to_dict(),
+        deep=SolverSession(config=cfg_dp, cache=cache).solve(g_dp).to_dict(),
+        spill=SolverSession(config=cfg_sp, cache=cache).solve(g_sp).to_dict(),
+        n=g.n,
+        plain_wall_s=plain_wall,
+        checkpointed_wall_s=ckpt_wall,
+        checkpoints_written=ck_run.stats.checkpoints_written,
+        checkpoint_bytes=ckpt_bytes,
+    )
+
+
+def _child(role: str, ckpt_dir: str, smoke: bool, spill: bool, deep: bool,
+           out: str) -> None:
+    from repro.api import SolverSession
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
+    result = None
+    if role == "baseline":
+        result = _baseline(smoke)
+    elif role == "solve":
+        g, cfg = _workload(smoke, spill, deep)
+        SolverSession(config=cfg).solve(g, checkpoint_dir=ckpt_dir)
+    elif role == "recover":
+        # resume from the survivors AND keep checkpointing into the same
+        # directory — so the parent can SIGKILL it again mid-recovery
         SolverSession.resume(ckpt_dir, checkpoint_dir=ckpt_dir)
-        return
-    g, cfg = _workload(smoke, spill, deep)
-    SolverSession(config=cfg).solve(g, checkpoint_dir=ckpt_dir)
+    else:  # "resume": the final, uninterrupted restore
+        t0 = time.perf_counter()
+        r = SolverSession.resume(ckpt_dir, checkpoint_dir=None)
+        result = dict(r.to_dict(), resume_wall_s=time.perf_counter() - t0)
+    if out is not None:
+        with open(out, "w") as f:
+            json.dump(result, f)
+
+
+def _argv(role: str, ckpt_dir: str, smoke: bool, spill: bool = False,
+          deep: bool = False, out: str | None = None) -> list:
+    return (
+        [sys.executable, "-m", "benchmarks.resume_smoke", "--child", role,
+         "--dir", ckpt_dir]
+        + (["--smoke"] if smoke else [])
+        + (["--spill"] if spill else [])
+        + (["--deep"] if deep else [])
+        + (["--out", out] if out else [])
+    )
+
+
+def _env() -> dict:
+    return {**os.environ, "PYTHONPATH": "src"}
+
+
+def _run_child(role: str, ckpt_dir: str, smoke: bool, **kw) -> dict:
+    """Run one child to completion and return the JSON it wrote."""
+    out = f"{ckpt_dir}.{role}.json"
+    subprocess.run(
+        _argv(role, ckpt_dir, smoke, out=out, **kw), env=_env(), check=True
+    )
+    with open(out) as f:
+        return json.load(f)
+
+
+def _kill_when(proc, ready, poll_s: float, what: str) -> bool:
+    """SIGKILL ``proc`` once ``ready()`` holds; False if it exited first."""
+    deadline = time.time() + 300
+    while time.time() < deadline:
+        if ready():
+            proc.send_signal(signal.SIGKILL)
+            proc.wait()
+            return True
+        if proc.poll() is not None:
+            return False
+        time.sleep(poll_s)
+    proc.kill()
+    proc.wait()
+    raise RuntimeError(f"{what} within 300s")
 
 
 def _dir_bytes(d: str) -> int:
@@ -105,201 +197,140 @@ def _dir_bytes(d: str) -> int:
     return total
 
 
-def _kill_and_resume(smoke: bool, cache, spill: bool = False):
+def _kill_and_resume(smoke: bool, spill: bool = False):
     """Launch the checkpointing child, SIGKILL it at the first durable
-    step, resume from the survivors.  Returns (resumed_result,
-    killed_at_step, killed_mid_solve, resume_wall_s)."""
-    from repro.api import SolverSession
-    from repro.checkpoint.store import latest_step
+    step, resume from the survivors in a fresh child.  Returns
+    (resumed_result, killed_at_step, killed_mid_solve)."""
+    from repro.checkpoint.store import latest_step  # reads dirs; no backend
 
     d = tempfile.mkdtemp(prefix="resume_smoke_kill_")
     try:
+        ckpt = os.path.join(d, "ckpt")
         proc = subprocess.Popen(
-            [sys.executable, "-m", "benchmarks.resume_smoke",
-             "--child", "--dir", d]
-            + (["--smoke"] if smoke else [])
-            + (["--spill"] if spill else []),
-            env={**os.environ, "PYTHONPATH": "src"},
+            _argv("solve", ckpt, smoke, spill=spill), env=_env()
         )
-        deadline = time.time() + 300
-        killed_mid_solve = False
-        while time.time() < deadline:
-            if latest_step(d) is not None:
-                proc.send_signal(signal.SIGKILL)
-                proc.wait()
-                killed_mid_solve = True
-                break
-            if proc.poll() is not None:
-                break  # solved before the first checkpoint landed
-            time.sleep(0.05)
-        else:
-            proc.kill()
-            proc.wait()
-            raise RuntimeError("child produced no checkpoint within 300s")
-        step = latest_step(d)
+        killed_mid_solve = _kill_when(
+            proc, lambda: latest_step(ckpt) is not None, 0.05,
+            "child produced no checkpoint",
+        )
+        step = latest_step(ckpt)
         assert step is not None, "no checkpoint survived the kill"
-
-        t0 = time.perf_counter()
-        resumed = SolverSession.resume(d, cache=cache, checkpoint_dir=None)
-        resume_wall = time.perf_counter() - t0
+        resumed = _run_child("resume", ckpt, smoke)
     finally:
         shutil.rmtree(d, ignore_errors=True)
-    return resumed, step, killed_mid_solve, resume_wall
+    return resumed, step, killed_mid_solve
 
 
-def _kill_mid_recovery(smoke: bool, cache):
+def _kill_mid_recovery(smoke: bool):
     """The double-kill cycle: SIGKILL the first child at its first durable
     step, then launch a RECOVERY child (it resumes from the survivors while
     continuing to checkpoint into the same directory) and SIGKILL that one
-    too once it has written a newer generation — the final in-process
-    resume must still land bit-identically.  Returns (resumed_result,
-    first_kill_step, recovery_kill_step, recovery_killed_mid_solve)."""
-    from repro.api import SolverSession
-    from repro.checkpoint.store import latest_step
+    too once it has written a newer generation — the final resume must
+    still land bit-identically.  Returns (resumed_result, first_kill_step,
+    recovery_kill_step, recovery_killed_mid_solve)."""
+    from repro.checkpoint.store import latest_step  # reads dirs; no backend
 
     d = tempfile.mkdtemp(prefix="resume_smoke_kill2_")
     try:
-        env = {**os.environ, "PYTHONPATH": "src"}
-        base_argv = (
-            [sys.executable, "-m", "benchmarks.resume_smoke",
-             "--child", "--dir", d, "--deep"]
-            + (["--smoke"] if smoke else [])
+        ckpt = os.path.join(d, "ckpt")
+        proc = subprocess.Popen(
+            _argv("solve", ckpt, smoke, deep=True), env=_env()
         )
-        proc = subprocess.Popen(base_argv, env=env)
-        deadline = time.time() + 300
-        while time.time() < deadline:
-            if latest_step(d) is not None:
-                proc.send_signal(signal.SIGKILL)
-                proc.wait()
-                break
-            if proc.poll() is not None:
-                break
-            time.sleep(0.002)
-        else:
-            proc.kill()
-            proc.wait()
-            raise RuntimeError("child produced no checkpoint within 300s")
-        step1 = latest_step(d)
+        _kill_when(
+            proc, lambda: latest_step(ckpt) is not None, 0.002,
+            "child produced no checkpoint",
+        )
+        step1 = latest_step(ckpt)
         assert step1 is not None, "no checkpoint survived the first kill"
 
         # recovery child: resumes from step1 and keeps checkpointing; kill
         # it again as soon as a NEWER generation is durable (mid-recovery).
         # If the remaining work finishes before that, the cycle degrades to
         # a plain resume — recorded, not failed.
-        proc = subprocess.Popen(base_argv + ["--resume"], env=env)
-        deadline = time.time() + 300
-        killed_mid_recovery = False
-        while time.time() < deadline:
-            latest = latest_step(d)
-            if latest is not None and latest > step1:
-                proc.send_signal(signal.SIGKILL)
-                proc.wait()
-                killed_mid_recovery = True
-                break
-            if proc.poll() is not None:
-                break  # recovery finished before writing a newer step
-            time.sleep(0.002)
-        else:
-            proc.kill()
-            proc.wait()
-            raise RuntimeError("recovery child made no progress within 300s")
-        step2 = latest_step(d)
+        proc = subprocess.Popen(
+            _argv("recover", ckpt, smoke, deep=True), env=_env()
+        )
+        killed_mid_recovery = _kill_when(
+            proc,
+            lambda: (latest_step(ckpt) or -1) > step1,
+            0.002,
+            "recovery child made no progress",
+        )
+        step2 = latest_step(ckpt)
         assert step2 is not None and step2 >= step1
-
-        resumed = SolverSession.resume(d, cache=cache, checkpoint_dir=None)
+        resumed = _run_child("resume", ckpt, smoke)
     finally:
         shutil.rmtree(d, ignore_errors=True)
     return resumed, step1, step2, killed_mid_recovery
 
 
+def _same(got: dict, want: dict, keys) -> None:
+    for key in keys:
+        assert got[key] == want[key], (key, got[key], want[key])
+
+
 def run(smoke: bool = False) -> dict:
-    from repro.api import PlaneCache, SolverSession
-    from repro.checkpoint.store import latest_step
-
-    g, cfg = _workload(smoke)
-    cache = PlaneCache()
-
-    # warm the plane cache first so plain-vs-checkpointed walls compare
-    # steady-state write cost, not one run's compile against the other's hit
-    SolverSession(config=cfg, cache=cache).solve(g)
-    t0 = time.perf_counter()
-    base = SolverSession(config=cfg, cache=cache).solve(g)
-    plain_wall = time.perf_counter() - t0
-
-    # checkpoint write overhead: same solve, every chunk durable, in-process
-    d_cost = tempfile.mkdtemp(prefix="resume_smoke_cost_")
+    d = tempfile.mkdtemp(prefix="resume_smoke_base_")
     try:
-        t0 = time.perf_counter()
-        ck_run = SolverSession(config=cfg, cache=cache).solve(
-            g, checkpoint_dir=d_cost
-        )
-        ckpt_wall = time.perf_counter() - t0
-        ckpt_bytes = _dir_bytes(os.path.join(d_cost, f"step_{latest_step(d_cost)}"))
-        writes = ck_run.stats.checkpoints_written
+        baseline = _run_child("baseline", os.path.join(d, "base"), smoke)
     finally:
-        shutil.rmtree(d_cost, ignore_errors=True)
+        shutil.rmtree(d, ignore_errors=True)
+    base = baseline["main"]
+    plain_wall = baseline["plain_wall_s"]
+    ckpt_wall = baseline["checkpointed_wall_s"]
 
-    resumed, step, killed_mid_solve, resume_wall = _kill_and_resume(
-        smoke, cache
-    )
+    resumed, step, killed_mid_solve = _kill_and_resume(smoke)
+    resume_wall = resumed["resume_wall_s"]
 
     # bit-identity vs the uninterrupted baseline (wall_s and the durability
     # counters are explicitly outside the contract)
-    assert resumed.best_size == base.best_size
-    assert resumed.rounds == base.rounds
-    assert resumed.nodes_expanded == base.nodes_expanded
-    assert resumed.tasks_transferred == base.tasks_transferred
-    assert resumed.stats.transfer_bytes_total == base.stats.transfer_bytes_total
-    assert (np.asarray(resumed.best_sol) == np.asarray(base.best_sol)).all()
+    _same(resumed, base, (
+        "best_size", "rounds", "nodes_expanded", "tasks_transferred",
+        "best_sol",
+    ))
+    _same(resumed["stats"], base["stats"], ("transfer_bytes_total",))
 
     # double-kill cycle: SIGKILL the solve, then SIGKILL the recovery
     # itself mid-checkpoint — the second-generation survivors must still
     # resume bit-identically (checkpoints are valid at EVERY boundary,
     # including ones written by a recovering process)
-    g_dp, cfg_dp = _workload(smoke, deep=True)
-    base_dp = SolverSession(config=cfg_dp, cache=cache).solve(g_dp)
     res2, kill1_step, kill2_step, killed_mid_recovery = _kill_mid_recovery(
-        smoke, cache
+        smoke
     )
-    assert res2.best_size == base_dp.best_size
-    assert res2.rounds == base_dp.rounds
-    assert res2.nodes_expanded == base_dp.nodes_expanded
-    assert (np.asarray(res2.best_sol) == np.asarray(base_dp.best_sol)).all()
+    _same(res2, baseline["deep"], (
+        "best_size", "rounds", "nodes_expanded", "best_sol",
+    ))
 
     # second cycle: SIGKILL with a live cold tier (frontier_spill on a
     # saturating capacity) — resume must replay the spill pump exactly
-    g_sp, cfg_sp = _workload(smoke, spill=True)
-    base_sp = SolverSession(
-        problem="vertex_cover", config=cfg_sp, cache=cache
-    ).solve(g_sp)
-    assert base_sp.stats.spilled_tasks > 0, (
+    base_sp = baseline["spill"]
+    assert base_sp["stats"]["spilled_tasks"] > 0, (
         "spill workload no longer saturates — retune _workload(spill=True)"
     )
-    res_sp, sp_step, sp_killed, _ = _kill_and_resume(smoke, cache, spill=True)
-    assert res_sp.best_size == base_sp.best_size
-    assert res_sp.rounds == base_sp.rounds
-    assert res_sp.nodes_expanded == base_sp.nodes_expanded
-    assert (
-        np.asarray(res_sp.best_sol) == np.asarray(base_sp.best_sol)
-    ).all()
-    assert res_sp.stats.spilled_tasks == base_sp.stats.spilled_tasks
-    assert res_sp.stats.readmitted_tasks == base_sp.stats.readmitted_tasks
-    assert res_sp.stats.overflow_count == 0 and not res_sp.stats.overflow
+    res_sp, sp_step, sp_killed = _kill_and_resume(smoke, spill=True)
+    _same(res_sp, base_sp, (
+        "best_size", "rounds", "nodes_expanded", "best_sol",
+    ))
+    _same(res_sp["stats"], base_sp["stats"], (
+        "spilled_tasks", "readmitted_tasks",
+    ))
+    assert res_sp["stats"]["overflow_count"] == 0
+    assert not res_sp["stats"]["overflow"]
 
     out = dict(
-        n=g.n,
-        rounds=int(base.rounds),
+        n=baseline["n"],
+        rounds=int(base["rounds"]),
         killed_mid_solve=killed_mid_solve,
         killed_at_step=int(step),
-        resumed_best=int(resumed.best_size),
+        resumed_best=int(resumed["best_size"]),
         bit_identical=True,
         plain_wall_s=round(plain_wall, 3),
         checkpointed_wall_s=round(ckpt_wall, 3),
         checkpoint_overhead_pct=round(
             100.0 * (ckpt_wall - plain_wall) / max(plain_wall, 1e-9), 1
         ),
-        checkpoints_written=int(writes),
-        checkpoint_bytes=int(ckpt_bytes),
+        checkpoints_written=int(baseline["checkpoints_written"]),
+        checkpoint_bytes=int(baseline["checkpoint_bytes"]),
         resume_wall_s=round(resume_wall, 3),
         recovery_first_kill_step=int(kill1_step),
         recovery_second_kill_step=int(kill2_step),
@@ -307,9 +338,9 @@ def run(smoke: bool = False) -> dict:
         recovery_bit_identical=True,
         spill_killed_at_step=int(sp_step),
         spill_killed_mid_solve=sp_killed,
-        spill_resumed_best=int(res_sp.best_size),
-        spill_spilled_tasks=int(res_sp.stats.spilled_tasks),
-        spill_readmitted_tasks=int(res_sp.stats.readmitted_tasks),
+        spill_resumed_best=int(res_sp["best_size"]),
+        spill_spilled_tasks=int(res_sp["stats"]["spilled_tasks"]),
+        spill_readmitted_tasks=int(res_sp["stats"]["readmitted_tasks"]),
         spill_bit_identical=True,
     )
     print(
@@ -346,14 +377,19 @@ def run(smoke: bool = False) -> dict:
 def main(argv=None) -> None:
     ap = argparse.ArgumentParser(prog="benchmarks.resume_smoke")
     ap.add_argument("--smoke", action="store_true")
-    ap.add_argument("--child", action="store_true", help=argparse.SUPPRESS)
+    ap.add_argument(
+        "--child", default=None, help=argparse.SUPPRESS,
+        choices=("baseline", "solve", "recover", "resume"),
+    )
     ap.add_argument("--dir", default=None, help=argparse.SUPPRESS)
     ap.add_argument("--spill", action="store_true", help=argparse.SUPPRESS)
-    ap.add_argument("--resume", action="store_true", help=argparse.SUPPRESS)
     ap.add_argument("--deep", action="store_true", help=argparse.SUPPRESS)
+    ap.add_argument("--out", default=None, help=argparse.SUPPRESS)
     args = ap.parse_args(argv)
     if args.child:
-        _child(args.dir, args.smoke, args.spill, args.resume, args.deep)
+        _child(
+            args.child, args.dir, args.smoke, args.spill, args.deep, args.out
+        )
     else:
         run(args.smoke)
 

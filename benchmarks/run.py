@@ -11,11 +11,15 @@
   serve_load        continuous-admission service vs fixed batching (gated)
   spill_throughput  hierarchical frontier memory: no-drop + wall gate
   chaos_smoke       seeded fault schedule: bit-identical self-healing gate
-  resume_smoke      SIGKILL mid-solve + bit-identical resume (durability gate)
   balancer_bench    beyond-paper serving balancer
   kernel_bench      kernel arithmetic-intensity table
 
 Usage:  PYTHONPATH=src python -m benchmarks.run [--smoke] [name ...]
+
+The kill-and-resume durability gate is not in this list: its solves run in
+child processes, and a chip belongs to one process at a time, so it runs as
+its own command (``python -m benchmarks.resume_smoke``), never after this
+process has used JAX.
 
 ``--smoke`` runs shrunken versions of the smoke-capable benchmarks (the
 default name set becomes SMOKE_DEFAULT) and records every dict a benchmark
@@ -43,12 +47,12 @@ from benchmarks import (
     explore_throughput,
     kernel_bench,
     protocol_stats,
-    resume_smoke,
     serve_load,
     session_warm,
     speedup,
     spill_throughput,
 )
+from repro.launch.compile_cache import enable_compile_cache
 
 ALL = {
     "encoding_bytes": encoding_bytes,
@@ -61,7 +65,6 @@ ALL = {
     "serve_load": serve_load,
     "spill_throughput": spill_throughput,
     "chaos_smoke": chaos_smoke,
-    "resume_smoke": resume_smoke,
     "balancer_bench": balancer_bench,
     "kernel_bench": kernel_bench,
     "speedup": speedup,
@@ -88,6 +91,7 @@ def main(argv=None) -> None:
         help=f"shrunken sizes; record results in {SMOKE_JSON}",
     )
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     names = args.names or (
         list(SMOKE_DEFAULT) if args.smoke else list(ALL)

@@ -90,21 +90,16 @@ TRANSFER_IMPLS = ("sparse", "gather")
 
 
 def _shard_map(body, *, mesh, in_specs, out_specs):
-    """shard_map across jax versions (top-level on newer, experimental on
-    0.4.x).  The 0.4.x replication checker has no rule for ``while`` — the
-    chunked runner's device-resident loop — so replication checking is
-    disabled where the kwarg exists.  Kept local so :mod:`repro.core` stays
-    launch-independent."""
-    fn = getattr(jax, "shard_map", None)
-    if fn is None:
-        from jax.experimental.shard_map import shard_map as fn
-    try:
-        return fn(
-            body, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_rep=False,
-        )
-    except TypeError:  # newer jax renamed/removed check_rep
-        return fn(body, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
+    """``jax.shard_map`` with the varying-axes check off: the chunked
+    runner's ``lax.while_loop`` carries the worker state sliced from the
+    sharded input (typed as varying over the worker axis), and the
+    pmin/psum that update scalars such as ``best_val`` return values the
+    checker types as replicated, so the loop carry would change type.  Kept
+    local so :mod:`repro.core` stays launch-independent."""
+    return jax.shard_map(
+        body, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+        check_vma=False,
+    )
 
 
 class WorkerState(NamedTuple):

@@ -2,17 +2,24 @@
 compute hot spot).
 
 TPU-native rethink of the GPU bitset tricks (no warp ballots / popc
-intrinsics assumed): the adjacency bitset matrix ``(n, W)`` lives wholly in
-VMEM (n ≤ 2048 ⇒ ≤ 512 KiB), a grid over task blocks streams packed task
+intrinsics assumed): the adjacency bitset matrix lives wholly in VMEM,
+transposed to ``(W, n)`` so one packed word of every vertex is one sublane
+row (n ≤ 2048 ⇒ ≤ 512 KiB), a grid over task blocks streams packed task
 masks through the VPU, and popcount is a SWAR reduction (shift/mask adds) so
 it vectorizes over the (8, 128) VREG tile regardless of Mosaic popcount
 support.  Degrees come out as an ``(T, n)`` int32 panel: one AND + popcount
-per (task, vertex, word) triple, reduced over words with a fori_loop so the
-VMEM working set stays at ``BT × n`` instead of ``BT × n × W``.
+per (task, vertex, word) triple, accumulated over words so the VMEM working
+set stays at ``BT × n`` instead of ``BT × n × W``.
 
-Grid:  (ceil(T / BT),)
+Mosaic lowers only static slices of the lane (minor) axis, so the word loop
+is unrolled at trace time (W ≤ 64 inside the documented n ≤ 2048): word
+``w`` is the static column ``masks[:, w:w+1]`` and the static row
+``adj_t[w:w+1, :]``.  The same pass gathers each vertex's own mask word
+(``masks[t, v // 32]``) with a static select, which replaces a lane gather.
+
+Grid:  (ceil(T / BT),)     BT a multiple of 8, or the whole T
   masks block  (BT, W)   VMEM
-  adj          (n, W)    VMEM (whole matrix, every grid step)
+  adj_t        (W, n)    VMEM (whole matrix, every grid step)
   out block    (BT, n)   VMEM
 
 ``batched_expand_stats`` is the fused exploration plane's kernel: the same
@@ -69,69 +76,48 @@ def _swar_popcount_u32(x: jnp.ndarray) -> jnp.ndarray:
     return ((x * jnp.uint32(0x01010101)) >> 24).astype(jnp.int32)
 
 
-def _degrees_kernel(masks_ref, adj_ref, out_ref, *, n: int, W: int):
-    BT = masks_ref.shape[0]
-    masks = masks_ref[...]  # (BT, W) uint32
+def _degree_panel(masks, adj_t_ref, *, n: int, W: int):
+    """(BT, W) masks × (W, n) adjacency -> (BT, n) degrees, -1 outside the
+    task."""
+    BT = masks.shape[0]
+    v = jax.lax.broadcasted_iota(jnp.int32, (1, n), 1)
+    word_of_v = v // WORD_BITS
+    deg = jnp.zeros((BT, n), jnp.int32)
+    own = jnp.zeros((BT, n), jnp.uint32)  # masks[t, v // 32]
+    for w in range(W):  # static: Mosaic slices the lane axis statically only
+        mw = masks[:, w : w + 1]  # (BT, 1)
+        aw = adj_t_ref[w : w + 1, :]  # (1, n)
+        deg = deg + _swar_popcount_u32(mw & aw)
+        own = jnp.where(word_of_v == w, mw, own)
+    inside = (own >> (v % WORD_BITS).astype(jnp.uint32)) & jnp.uint32(1)
+    return jnp.where(inside != 0, deg, jnp.int32(-1))
 
-    def word_step(w, acc):
-        mw = masks[:, w]  # (BT,)
-        aw = adj_ref[:, w]  # (n,)
-        inter = mw[:, None] & aw[None, :]  # (BT, n)
-        return acc + _swar_popcount_u32(inter)
 
-    deg = jax.lax.fori_loop(
-        0, W, word_step, jnp.zeros((BT, n), jnp.int32)
-    )
+def _row_popcount(x, W: int):
+    """(BT, W) -> (BT, 1) popcount per row; stays 2-D because TPU vregs want
+    a lane axis."""
+    return sum(_swar_popcount_u32(x[:, w : w + 1]) for w in range(W))
 
-    # mask out vertices not in the task: bit v of masks word v//32
-    v = jax.lax.broadcasted_iota(jnp.int32, (BT, n), 1)
-    word_idx = v // WORD_BITS
-    bit_idx = (v % WORD_BITS).astype(jnp.uint32)
-    mask_words = jnp.take_along_axis(masks, word_idx.astype(jnp.int32), axis=1)
-    inside = ((mask_words >> bit_idx) & 1).astype(bool)
-    out_ref[...] = jnp.where(inside, deg, jnp.int32(-1))
+
+def _degrees_kernel(masks_ref, adj_t_ref, out_ref, *, n: int, W: int):
+    out_ref[...] = _degree_panel(masks_ref[...], adj_t_ref, n=n, W=W)
 
 
 def _expand_stats_kernel(
-    masks_ref, sols_ref, adj_ref, deg_ref, pc_ref, *, n: int, W: int
+    masks_ref, sols_ref, adj_t_ref, deg_ref, pc_ref, *, n: int, W: int
 ):
     """Fused panel: degrees (BT, n) + [pc_mask, pc_sol] (BT, 2) per block."""
-    BT = masks_ref.shape[0]
     masks = masks_ref[...]  # (BT, W) uint32
-    sols = sols_ref[...]  # (BT, W) uint32
-
-    def word_step(w, carry):
-        deg, pcm, pcs = carry
-        mw = masks[:, w]  # (BT,)
-        sw = sols[:, w]  # (BT,)
-        aw = adj_ref[:, w]  # (n,)
-        inter = mw[:, None] & aw[None, :]  # (BT, n)
-        # popcount accumulators stay 2-D (BT, 1): TPU vregs want a lane axis
-        return (
-            deg + _swar_popcount_u32(inter),
-            pcm + _swar_popcount_u32(mw[:, None]),
-            pcs + _swar_popcount_u32(sw[:, None]),
-        )
-
-    deg, pc_mask, pc_sol = jax.lax.fori_loop(
-        0,
-        W,
-        word_step,
-        (
-            jnp.zeros((BT, n), jnp.int32),
-            jnp.zeros((BT, 1), jnp.int32),
-            jnp.zeros((BT, 1), jnp.int32),
-        ),
+    deg_ref[...] = _degree_panel(masks, adj_t_ref, n=n, W=W)
+    col = jax.lax.broadcasted_iota(jnp.int32, pc_ref.shape, 1)
+    pc_ref[...] = jnp.where(
+        col == 0, _row_popcount(masks, W), _row_popcount(sols_ref[...], W)
     )
 
-    # mask out vertices not in the task: bit v of masks word v//32
-    v = jax.lax.broadcasted_iota(jnp.int32, (BT, n), 1)
-    word_idx = v // WORD_BITS
-    bit_idx = (v % WORD_BITS).astype(jnp.uint32)
-    mask_words = jnp.take_along_axis(masks, word_idx.astype(jnp.int32), axis=1)
-    inside = ((mask_words >> bit_idx) & 1).astype(bool)
-    deg_ref[...] = jnp.where(inside, deg, jnp.int32(-1))
-    pc_ref[...] = jnp.concatenate([pc_mask, pc_sol], axis=1)
+
+def _task_block(T: int, block_tasks: int) -> int:
+    """Rows per grid step: a multiple of 8 (the sublane tile), or all of T."""
+    return min(T, -(-block_tasks // 8) * 8)
 
 
 @functools.partial(jax.jit, static_argnames=("block_tasks", "interpret"))
@@ -154,7 +140,7 @@ def batched_expand_stats(
         interpret = default_interpret()
     n, W = adj.shape
     T = masks.shape[0]
-    BT = min(block_tasks, T)
+    BT = _task_block(T, block_tasks)
     grid = (pl.cdiv(T, BT),)
     return pl.pallas_call(
         functools.partial(_expand_stats_kernel, n=n, W=W),
@@ -162,7 +148,7 @@ def batched_expand_stats(
         in_specs=[
             pl.BlockSpec((BT, W), lambda i: (i, 0)),  # task masks block
             pl.BlockSpec((BT, W), lambda i: (i, 0)),  # task sols block
-            pl.BlockSpec((n, W), lambda i: (0, 0)),  # whole adjacency
+            pl.BlockSpec((W, n), lambda i: (0, 0)),  # whole adjacency
         ],
         out_specs=[
             pl.BlockSpec((BT, n), lambda i: (i, 0)),
@@ -173,7 +159,7 @@ def batched_expand_stats(
             jax.ShapeDtypeStruct((T, 2), jnp.int32),
         ],
         interpret=interpret,
-    )(masks, sols, adj)
+    )(masks, sols, adj.T)
 
 
 @functools.partial(jax.jit, static_argnames=("block_tasks", "interpret"))
@@ -193,16 +179,16 @@ def batched_degrees(
         interpret = default_interpret()
     n, W = adj.shape
     T = masks.shape[0]
-    BT = min(block_tasks, T)
+    BT = _task_block(T, block_tasks)
     grid = (pl.cdiv(T, BT),)
     return pl.pallas_call(
         functools.partial(_degrees_kernel, n=n, W=W),
         grid=grid,
         in_specs=[
             pl.BlockSpec((BT, W), lambda i: (i, 0)),  # task masks block
-            pl.BlockSpec((n, W), lambda i: (0, 0)),  # whole adjacency
+            pl.BlockSpec((W, n), lambda i: (0, 0)),  # whole adjacency
         ],
         out_specs=pl.BlockSpec((BT, n), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((T, n), jnp.int32),
         interpret=interpret,
-    )(masks, adj)
+    )(masks, adj.T)

@@ -177,8 +177,6 @@ def _compile_cell(cfg, shape, mesh, rules):
 
 def _cost_of(compiled):
     cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):  # 0.4.x jax: one dict per device
-        cost = cost[0] if cost else {}
     coll = collective_bytes(compiled.as_text())
     return (
         float(cost.get("flops", 0.0)),

@@ -32,7 +32,6 @@ from repro.core.superstep import (
 from repro.graphs.bitgraph import n_words
 from repro.graphs.generators import erdos_renyi
 from repro.launch.analysis import collective_bytes, roofline
-from repro.launch.mesh import make_mesh_compat
 from repro.problems.base import make_data
 from repro.problems.registry import get_problem
 
@@ -41,7 +40,9 @@ def lower_engine(n: int, workers: int, *, packed_status, skip_empty_transfer,
                  transfer_impl="gather", steps_per_round=32, lanes=1,
                  codec_pad=0, chunked=False, chunk_rounds=16,
                  problem="vertex_cover"):
-    mesh = make_mesh_compat((workers,), ("workers",))
+    mesh = jax.make_mesh(
+        (workers,), ("workers",), axis_types=(jax.sharding.AxisType.Auto,)
+    )
     g = erdos_renyi(n, 4.0 / (n - 1), 0)
     spec = get_problem(problem)
     data = make_data(spec, g)
@@ -69,8 +70,6 @@ def lower_engine(n: int, workers: int, *, packed_status, skip_empty_transfer,
     lowered = fn.lower(state)
     compiled = lowered.compile()
     cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):  # jax 0.4.x: one dict per device
-        cost = cost[0] if cost else {}
     coll = collective_bytes(compiled.as_text())
     mem = compiled.memory_analysis()
     flops = float(cost.get("flops", 0.0))

@@ -1,26 +1,11 @@
 """Production mesh construction (a FUNCTION so importing never touches jax
 device state — required by the dry-run's device-count override ordering).
-
-``make_mesh_compat`` papers over the jax API skew around explicit axis types:
-``jax.sharding.AxisType`` (and the matching ``axis_types=`` kwarg of
-``jax.make_mesh``) only exists on newer jax; on 0.4.37 every mesh axis is
-implicitly Auto, so omitting the kwarg is semantically identical.  All mesh
-construction in this repo (and in tests) must go through this helper.
-"""
+Every mesh axis is ``Auto``: sharding is propagated by the compiler."""
 
 from __future__ import annotations
 
 import jax
-
-
-def make_mesh_compat(shape, axis_names):
-    """``jax.make_mesh`` with Auto axis types where the API supports them."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(shape, axis_names)
-    return jax.make_mesh(
-        shape, axis_names, axis_types=(axis_type.Auto,) * len(axis_names)
-    )
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -30,13 +15,13 @@ def make_production_mesh(*, multi_pod: bool = False):
     only cross-pod collective is the gradient all-reduce, DCN-friendly)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh_compat(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_solver_mesh(num_workers: int | None = None):
     """1-D mesh for the branching engine: one worker per device."""
     n = num_workers or len(jax.devices())
-    return make_mesh_compat((n,), ("workers",))
+    return jax.make_mesh((n,), ("workers",), axis_types=(AxisType.Auto,))
 
 
 def batch_axes_for(global_batch: int, mesh) -> tuple | None:

@@ -26,6 +26,8 @@ import time
 
 import numpy as np
 
+from repro.launch.compile_cache import enable_compile_cache
+
 
 def build_requests(args, rng) -> list:
     """The synthetic arrival trace: (arrival_s, graph) pairs.  Sizes are
@@ -139,6 +141,7 @@ def main():
     ap.add_argument("--json", action="store_true",
                     help="print the full stats dict as JSON")
     args = ap.parse_args()
+    enable_compile_cache()
     if args.smoke:
         args.requests = min(args.requests, 12)
         args.n = min(args.n, 20)

@@ -41,6 +41,7 @@ import argparse
 import sys
 
 from repro.graphs.generators import erdos_renyi, p_hat_like, parse_dimacs
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def build_graph(args, seed=None):
@@ -218,6 +219,7 @@ def main():
     ap.add_argument("--chaos-seed", type=int, default=0,
                     help="seed for the --chaos fault plan (default 0)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.resume:
         resume_solve(args)

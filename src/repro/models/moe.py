@@ -25,17 +25,6 @@ from repro.configs.base import ModelConfig
 from repro.models.sharding import constrain
 
 
-def _get_shard_map():
-    """shard_map across jax versions (top-level on newer releases,
-    ``jax.experimental.shard_map`` on 0.4.x)."""
-    fn = getattr(jax, "shard_map", None)
-    if fn is not None:
-        return fn
-    from jax.experimental.shard_map import shard_map
-
-    return shard_map
-
-
 def moe_init(key, cfg: ModelConfig):
     d, f, E = cfg.d_model, cfg.d_ff, cfg.n_experts
     dt = jnp.dtype(cfg.dtype)
@@ -246,7 +235,7 @@ def _moe_shard_map(cfg: ModelConfig, p, x, rules):
 
     xr = x.reshape(G, Tg, d)
     dspec = data_axes if len(data_axes) > 1 else (data_axes[0] if data_axes else None)
-    out, aux = _get_shard_map()(
+    out, aux = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(

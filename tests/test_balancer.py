@@ -2,7 +2,7 @@
 
 import numpy as np
 import pytest
-from _hypothesis_compat import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.serving.balancer import (
     BalancerState,

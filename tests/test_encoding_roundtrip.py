@@ -10,6 +10,7 @@ sizes in EXPERIMENTS can never drift from the implementation.
 """
 
 import numpy as np
+from hypothesis import given, settings, strategies as st
 
 from repro.core.encoding import (
     CODECS,
@@ -20,8 +21,6 @@ from repro.core.encoding import (
 )
 from repro.graphs.bitgraph import n_words
 from repro.graphs.generators import erdos_renyi
-
-from tests._hypothesis_compat import given, settings, strategies as st
 
 
 class _Problem:

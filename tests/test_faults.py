@@ -32,6 +32,7 @@ import time
 
 import numpy as np
 import pytest
+from hypothesis import given, settings, strategies as st
 
 from repro.api import PlaneCache, SolveConfig, SolverSession, SolveTimeout
 from repro.api.service import AsyncSolveService
@@ -53,7 +54,6 @@ from repro.core.spill import FrontierSpiller
 from repro.faults import FAULT_KINDS, FaultEvent, FaultInjector, FaultPlan
 from repro.graphs.generators import erdos_renyi
 from repro.problems.sequential import solve_sequential
-from tests._hypothesis_compat import given, settings, strategies as st
 
 # one warm plane cache for the whole module: property examples re-solve the
 # same shapes many times and must not recompile each time

@@ -4,7 +4,7 @@ plus the backend-aware kernel-mode selection."""
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from _hypothesis_compat import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.graphs.generators import erdos_renyi
 from repro.kernels.bitset_ops import (

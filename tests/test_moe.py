@@ -3,9 +3,9 @@
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import AxisType
 
 from repro.configs.base import ModelConfig
-from repro.launch.mesh import make_mesh_compat
 from repro.models.moe import moe_apply, moe_init
 
 
@@ -26,7 +26,7 @@ def test_group_invariance_with_ample_capacity():
     p, _ = moe_init(jax.random.key(0), cfg)
     x = jax.random.normal(jax.random.key(1), (4, 8, 32))
     out1, aux1 = moe_apply(cfg, p, x, rules=None)  # G=1
-    mesh = make_mesh_compat((1, 1), ("data", "model"))
+    mesh = jax.make_mesh((1, 1), ("data", "model"), axis_types=(AxisType.Auto,) * 2)
     rules = {"batch": ("data",), "_sizes": {"data": 4}}
     with mesh:
         out4, aux4 = moe_apply(cfg, p, x, rules=rules)

@@ -6,7 +6,7 @@ with in-flight tasks (§3.3).
 """
 
 import pytest
-from _hypothesis_compat import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.core.centralized import run_centralized_sim
 from repro.core.protocol_sim import run_protocol_sim

@@ -2,9 +2,8 @@
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import PartitionSpec as P
+from jax.sharding import AxisType, PartitionSpec as P
 
-from repro.launch.mesh import make_mesh_compat
 from repro.models.moe import num_groups
 from repro.models.sharding import (
     DEFAULT_RULES,
@@ -17,7 +16,7 @@ from repro.models.sharding import (
 
 
 def _mesh11():
-    return make_mesh_compat((1, 1), ("data", "model"))
+    return jax.make_mesh((1, 1), ("data", "model"), axis_types=(AxisType.Auto,) * 2)
 
 
 def test_logical_to_spec():

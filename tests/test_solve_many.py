@@ -9,7 +9,7 @@ axis.
 
 import jax.numpy as jnp
 import numpy as np
-from _hypothesis_compat import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import engine as E
 from repro.core.frontier import Frontier

@@ -2,7 +2,7 @@
 
 import random
 
-from _hypothesis_compat import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.core.task_tree import TaskTree
 

@@ -10,7 +10,7 @@
 import jax
 import jax.numpy as jnp
 import numpy as np
-from _hypothesis_compat import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.core.frontier import make_frontier, pop_k_shallowest, push_many
 from repro.core.superstep import build_superstep_fn, make_worker_state

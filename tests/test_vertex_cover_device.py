@@ -4,7 +4,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from _hypothesis_compat import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.graphs.bitgraph import mask_full, popcount_rows
 from repro.graphs.generators import erdos_renyi

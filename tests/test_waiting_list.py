@@ -1,6 +1,6 @@
 """Algorithm 7 (equitable-startup waiting lists): exactness + properties."""
 
-from _hypothesis_compat import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.core.waiting_list import (
     build_waiting_lists,
