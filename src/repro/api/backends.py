@@ -26,6 +26,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import tracing
 from repro.api.cache import PlaneCache
 from repro.api.config import SolveConfig
 from repro.api.result import (
@@ -111,7 +112,22 @@ def solve_spmd(
     redelivery inside the spiller, and checkpoint I/O errors retry under
     the injector's deterministic backoff policy.  Recovery re-executes a
     deterministic prefix, so the final result stays bit-identical.
+
+    Tracing (:mod:`repro.tracing`): the host spans ``repro:solve`` and,
+    inside it, ``solve.startup``, ``solve.chunk``, ``solve.spill_pump``,
+    ``solve.checkpoint``, ``solve.fetch_state`` and ``solve.extract``, all
+    with one request id; ``r.host_fetches`` / ``r.host_fetch_bytes`` count
+    every device-to-host fetch.
     """
+    req = tracing.new_request_id()
+    with tracing.span("solve", req):
+        return _solve_spmd(
+            spec, g, cfg, cache, req, initial_state=initial_state, mesh=mesh,
+            injector=injector,
+        )
+
+
+def _solve_spmd(spec, g, cfg, cache, req, *, initial_state, mesh, injector):
     k = cfg.solo_k()
     W = n_words(g.n)
     cap = cfg.capacity or (4 * g.n + 8 * cfg.lanes)
@@ -128,11 +144,14 @@ def solve_spmd(
         else None
     )
 
+    fetches = tracing.Fetches()
+
     def build_startup():
-        s = jax.vmap(
-            lambda _: _engine.make_worker_state(cap, W, initial_best)
-        )(jnp.arange(cfg.num_workers))
-        return _engine._scatter_startup(s, spec, g, cfg.num_workers)
+        with tracing.span("solve.startup", req):
+            s = jax.vmap(
+                lambda _: _engine.make_worker_state(cap, W, initial_best)
+            )(jnp.arange(cfg.num_workers))
+            return _engine._scatter_startup(s, spec, g, cfg.num_workers)
 
     rounds = 0
     resumed_from = None
@@ -224,24 +243,28 @@ def solve_spmd(
     chunks = 0
     checkpoints_written = 0
     while rounds < cfg.max_rounds:
-        state, done, ran, hot = step(state)
-        done, ran, hot = jax.device_get((done, ran, hot))
+        with tracing.span("solve.chunk", req):
+            state, done, ran, hot = step(state)
+            done, ran, hot = fetches.get((done, ran, hot))
         rounds += int(ran)
         chunks += 1
         done = bool(done)
         if spill is not None and spill.wants_pump(hot, done):
-            # an FPT bound hit finishes the solve regardless of cold backlog
-            # (quiescent-done without the bound must refill and continue)
-            fpt_hit = (
-                done
-                and use_fpt
-                and int(jax.device_get(state.best_val.min()))
-                <= int(spec.fpt_target(k))
-            )
-            if not fpt_hit:
-                frontier, hot = spill.pump_frontier(state.frontier)
-                state = state._replace(frontier=frontier)
-                done = done and int(hot.sum()) == 0
+            with tracing.span("solve.spill_pump", req):
+                # an FPT bound hit finishes the solve regardless of cold
+                # backlog (quiescent-done without the bound must refill and
+                # continue)
+                fpt_hit = (
+                    done
+                    and use_fpt
+                    and int(fetches.get(state.best_val.min()))
+                    <= int(spec.fpt_target(k))
+                )
+                if not fpt_hit:
+                    fetches.add(tracing.nbytes(_pool(state.frontier)))
+                    frontier, hot = spill.pump_frontier(state.frontier)
+                    state = state._replace(frontier=frontier)
+                    done = done and int(hot.sum()) == 0
         if injector is not None:
             injector.step_boundary()
             if injector.take_crash():
@@ -299,26 +322,34 @@ def solve_spmd(
             cfg.checkpoint_dir is not None
             and chunks % cfg.checkpoint_every == 0
         ):
-            _write_solo_checkpoint(
-                spec, g, cfg, fingerprint, state, rounds, spill,
-                retry=io_retry, fault_hook=io_hook,
-            )
+            with tracing.span("solve.checkpoint", req):
+                fetches.add(tracing.nbytes(state))
+                _write_solo_checkpoint(
+                    spec, g, cfg, fingerprint, state, rounds, spill,
+                    retry=io_retry, fault_hook=io_hook,
+                )
             checkpoints_written += 1
     wall = time.perf_counter() - t0
 
-    host = _engine._fetch_batch_state(jax.tree.map(lambda x: x[None], state))
-    r = _engine._extract_result(
-        host,
-        0,
-        spec,
-        g,
-        rounds,
-        wall,
-        mode=cfg.mode,
-        k=k,
-        num_workers=cfg.num_workers,
-        packed_status=cfg.packed_status,
-    )
+    with tracing.span("solve.fetch_state", req):
+        fetches.add(tracing.nbytes(state))
+        host = _engine._fetch_batch_state(
+            jax.tree.map(lambda x: x[None], state)
+        )
+    with tracing.span("solve.extract", req):
+        r = _engine._extract_result(
+            host,
+            0,
+            spec,
+            g,
+            rounds,
+            wall,
+            mode=cfg.mode,
+            k=k,
+            num_workers=cfg.num_workers,
+            packed_status=cfg.packed_status,
+        )
+    r.host_fetches, r.host_fetch_bytes = fetches.count, fetches.bytes
     r.checkpoints_written = checkpoints_written
     r.resumed_from = resumed_from
     if spill is not None:
@@ -326,6 +357,11 @@ def solve_spmd(
         r.readmitted_tasks = spill.readmitted_total
         r.cold_bytes_peak = spill.cold_bytes_peak
     return r
+
+
+def _pool(frontier):
+    """The frontier arrays a spill pump fetches."""
+    return (frontier.masks, frontier.sols, frontier.depths, frontier.active)
 
 
 def solve_many_spmd(spec, graphs, cfg: SolveConfig, cache: PlaneCache,
@@ -352,7 +388,16 @@ def solve_many_spmd(spec, graphs, cfg: SolveConfig, cache: PlaneCache,
     checkpoint never needs a lane that was compacted away; per-instance
     ``wall_s`` (the amortized bucket share) is patched at bucket end and
     is the one field outside the bit-identity contract.
+
+    Tracing: the spans of :func:`solve_spmd` under ``solve_many.*``; a
+    result's host fetches are those made while its bucket ran.
     """
+    req = tracing.new_request_id()
+    with tracing.span("solve_many", req):
+        return _solve_many_spmd(spec, graphs, cfg, cache, injector, req)
+
+
+def _solve_many_spmd(spec, graphs, cfg, cache, injector, req):
     from repro.core.superstep import (
         LaneState,
         lane_resume,
@@ -396,19 +441,32 @@ def solve_many_spmd(spec, graphs, cfg: SolveConfig, cache: PlaneCache,
             "many", spec.name, cfg, [_ckpt.graph_digest(g) for g in graphs]
         )
 
+    fetches = tracing.Fetches()
+    bucket_mark = (0, 0)  # fetches before the bucket in flight
+
     def extract(host, lane, oi, rounds_i, wall):
-        return _engine._extract_result(
-            host,
-            lane,
-            spec,
-            graphs[oi],
-            rounds_i,
-            wall,
-            mode=cfg.mode,
-            k=ks[oi],
-            num_workers=cfg.num_workers,
-            packed_status=cfg.packed_status,
-        )
+        with tracing.span("solve_many.extract", req):
+            r = _engine._extract_result(
+                host,
+                lane,
+                spec,
+                graphs[oi],
+                rounds_i,
+                wall,
+                mode=cfg.mode,
+                k=ks[oi],
+                num_workers=cfg.num_workers,
+                packed_status=cfg.packed_status,
+            )
+        r.host_fetches = fetches.count - bucket_mark[0]
+        r.host_fetch_bytes = fetches.bytes - bucket_mark[1]
+        return r
+
+    def fetch_state(lanes):
+        with tracing.span("solve_many.fetch_state", req):
+            fetches.add(tracing.nbytes(lanes.worker))
+            host = _engine._fetch_batch_state(lanes.worker)
+            return host, np.asarray(fetches.get(lanes.rounds))
 
     io_retry = injector.retry_policy() if injector is not None else None
     io_hook = injector.io_hook if injector is not None else None
@@ -451,6 +509,9 @@ def solve_many_spmd(spec, graphs, cfg: SolveConfig, cache: PlaneCache,
     def write_checkpoint(bi, lanes, datas, fpt_bounds, total_ran, spillers):
         from repro.checkpoint import solve as _ckpt
 
+        fetches.add(tracing.nbytes((lanes.worker, lanes.done, lanes.rounds)))
+        fetches.add(tracing.nbytes(datas))
+
         ck = _ckpt.SolveCheckpoint(
             kind="many",
             problem=spec.name,
@@ -474,7 +535,7 @@ def solve_many_spmd(spec, graphs, cfg: SolveConfig, cache: PlaneCache,
         )
         ck.arrays.update(_ckpt.data_to_flat(datas, "datas"))
         if fpt_bounds is not None:
-            ck.arrays["fpt_bounds"] = np.asarray(jax.device_get(fpt_bounds))
+            ck.arrays["fpt_bounds"] = np.asarray(fetches.get(fpt_bounds))
         for lane, sp in enumerate(spillers):
             if sp is not None:
                 ck.arrays.update(sp.to_flat(f"spill{lane}"))
@@ -490,6 +551,7 @@ def solve_many_spmd(spec, graphs, cfg: SolveConfig, cache: PlaneCache,
         if resume_ck is not None and bi < resume_bucket:
             continue  # fully finalized before the checkpoint — restored above
         t0 = time.perf_counter()
+        bucket_mark = (fetches.count, fetches.bytes)
         cap = cfg.capacity or (4 * n_max + 8 * cfg.lanes)
         pad = make_codec(cfg.codec, n_max, problem=spec).pad_words
 
@@ -502,7 +564,7 @@ def solve_many_spmd(spec, graphs, cfg: SolveConfig, cache: PlaneCache,
                 jnp.asarray(resume_ck.arrays["fpt_bounds"]) if use_fpt else None
             )
             total_ran = int(resume_ck.meta["total_ran"])
-            live_h = ~np.asarray(jax.device_get(lanes.done))
+            live_h = ~np.asarray(fetches.get(lanes.done))
             spillers = [None] * lanes.num_lanes
             if cfg.frontier_spill:
                 for lane in range(lanes.num_lanes):
@@ -521,15 +583,19 @@ def solve_many_spmd(spec, graphs, cfg: SolveConfig, cache: PlaneCache,
                 problems_base.initial_bound(spec, g, cfg.mode, ks[i])
                 for i, g in zip(idxs, bucket_graphs)
             ]
-            datas = problems_base.make_batch_data(spec, bucket_graphs, n_max, W)
-            lanes = LaneState(
-                worker=_engine._make_batch_state(
-                    spec, bucket_graphs, cfg.num_workers, cap, W, initial_bests
-                ),
-                done=jnp.zeros((len(idxs),), bool),
-                tag=np.asarray(idxs, np.int32),
-                rounds=jnp.zeros((len(idxs),), jnp.int32),
-            )
+            with tracing.span("solve_many.startup", req):
+                datas = problems_base.make_batch_data(
+                    spec, bucket_graphs, n_max, W
+                )
+                lanes = LaneState(
+                    worker=_engine._make_batch_state(
+                        spec, bucket_graphs, cfg.num_workers, cap, W,
+                        initial_bests,
+                    ),
+                    done=jnp.zeros((len(idxs),), bool),
+                    tag=np.asarray(idxs, np.int32),
+                    rounds=jnp.zeros((len(idxs),), jnp.int32),
+                )
             fpt_bounds = (
                 jnp.asarray(
                     np.array([spec.fpt_target(ks[i]) for i in idxs], np.int32)
@@ -560,8 +626,9 @@ def solve_many_spmd(spec, graphs, cfg: SolveConfig, cache: PlaneCache,
             lane_stats["chunk_calls"] += 1
             lane_stats["lane_chunks"] += lanes.num_lanes
             lane_stats["live_lane_chunks"] += int(live_h.sum())
-            lanes, ran, hot = step_lanes(plane, datas, lanes, fpt_bounds)
-            done_h, ran_h, hot_h = jax.device_get((lanes.done, ran, hot))
+            with tracing.span("solve_many.chunk", req):
+                lanes, ran, hot = step_lanes(plane, datas, lanes, fpt_bounds)
+                done_h, ran_h, hot_h = fetches.get((lanes.done, ran, hot))
             total_ran += int(ran_h)
             chunks_total += 1
             done_h = np.array(done_h)
@@ -573,15 +640,20 @@ def solve_many_spmd(spec, graphs, cfg: SolveConfig, cache: PlaneCache,
                         hot_h[lane], bool(done_h[lane])
                     ):
                         continue
-                    if bool(done_h[lane]) and use_fpt:
-                        if best_h is None:
-                            best_h = np.asarray(
-                                jax.device_get(lanes.worker.best_val)
-                            )[:, 0]
-                            bounds_h = np.asarray(jax.device_get(fpt_bounds))
-                        if int(best_h[lane]) <= int(bounds_h[lane]):
-                            continue  # FPT bound hit — finished for real
-                    lanes, hot_lane = sp.pump_lane(lanes, lane)
+                    with tracing.span("solve_many.spill_pump", req):
+                        if bool(done_h[lane]) and use_fpt:
+                            if best_h is None:
+                                best_h = np.asarray(
+                                    fetches.get(lanes.worker.best_val)
+                                )[:, 0]
+                                bounds_h = np.asarray(fetches.get(fpt_bounds))
+                            if int(best_h[lane]) <= int(bounds_h[lane]):
+                                continue  # FPT bound hit — finished for real
+                        fetches.add(
+                            tracing.nbytes(_pool(lanes.worker.frontier))
+                            // lanes.num_lanes
+                        )
+                        lanes, hot_lane = sp.pump_lane(lanes, lane)
                     hot_h[lane] = hot_lane
                     if bool(done_h[lane]) and int(hot_lane.sum()) > 0:
                         lanes = lane_resume(lanes, lane)
@@ -627,8 +699,7 @@ def solve_many_spmd(spec, graphs, cfg: SolveConfig, cache: PlaneCache,
                 # collect finished lanes now, keep live ones (plus frozen
                 # finished fillers up to the pow2 target), reslice every
                 # tensor — the SAME plane function serves the new width.
-                host = _engine._fetch_batch_state(lanes.worker)
-                rounds_h = np.asarray(jax.device_get(lanes.rounds))
+                host, rounds_h = fetch_state(lanes)
                 live = np.flatnonzero(~done_h)
                 fillers = np.flatnonzero(done_h)[: target - n_live]
                 for lane in np.flatnonzero(done_h):
@@ -651,13 +722,13 @@ def solve_many_spmd(spec, graphs, cfg: SolveConfig, cache: PlaneCache,
                 cfg.checkpoint_dir is not None
                 and chunks_total % cfg.checkpoint_every == 0
             ):
-                write_checkpoint(
-                    bi, lanes, datas, fpt_bounds, total_ran, spillers
-                )
+                with tracing.span("solve_many.checkpoint", req):
+                    write_checkpoint(
+                        bi, lanes, datas, fpt_bounds, total_ran, spillers
+                    )
                 checkpoints_written += 1
 
-        host = _engine._fetch_batch_state(lanes.worker)
-        rounds_h = np.asarray(jax.device_get(lanes.rounds))
+        host, rounds_h = fetch_state(lanes)
         for lane in range(lanes.num_lanes):
             oi = int(lanes.tag[lane])
             if oi not in results:

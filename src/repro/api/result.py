@@ -125,6 +125,20 @@ class SolveStats(_DictAccessShim):
     spilled_tasks: int = 0
     readmitted_tasks: int = 0
     cold_bytes_peak: int = 0
+    # -- explore's reduction (spmd; 0 without one) ----------------------------
+    # summed over workers: sweeps run by expanded lanes (each lane's last
+    # sweep changes nothing), the lockstep loop's trips (per explore step,
+    # the most sweeps of any lane of the worker), and each rule's firings
+    reduce_lane_sweeps: int = 0
+    reduce_worker_sweeps: int = 0
+    reduce_fires_rule1: int = 0
+    reduce_fires_rule2: int = 0
+    reduce_fires_rule3: int = 0
+    # -- host sync (spmd) -----------------------------------------------------
+    # device-to-host fetches of the host loop while the instance was on the
+    # plane (shared with its co-runners on a batched plane), and their bytes
+    host_fetches: int = 0
+    host_fetch_bytes: int = 0
     # -- discrete-event simulator backends ------------------------------------
     ticks: int = 0
     failed_requests: int = 0
@@ -274,6 +288,13 @@ def from_engine_result(r, *, problem: str, backend: str = "spmd") -> SolveResult
             spilled_tasks=r.spilled_tasks,
             readmitted_tasks=r.readmitted_tasks,
             cold_bytes_peak=r.cold_bytes_peak,
+            reduce_lane_sweeps=r.reduce_lane_sweeps,
+            reduce_worker_sweeps=r.reduce_worker_sweeps,
+            reduce_fires_rule1=r.reduce_fires_rule1,
+            reduce_fires_rule2=r.reduce_fires_rule2,
+            reduce_fires_rule3=r.reduce_fires_rule3,
+            host_fetches=r.host_fetches,
+            host_fetch_bytes=r.host_fetch_bytes,
         ),
     )
 

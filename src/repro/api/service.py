@@ -50,10 +50,10 @@ import threading
 import time
 from typing import Optional
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import tracing
 from repro.api.cache import PlaneCache
 from repro.api.config import SolveConfig
 from repro.api.result import ServiceStats, SolveResult, from_engine_result
@@ -224,6 +224,16 @@ class _LivePlane:
         self.fault_free = 0  # consecutive fault-free chunks (heals shedding)
         self.last_rounds: list = [0] * B
         self.stall_chunks: list = [0] * B
+        # the host's device-to-host fetches for this plane, and where each
+        # lane's occupant came in (its result reports the fetches since)
+        self.fetches = tracing.Fetches()
+        self.fetch_mark: list = [(0, 0)] * B
+
+    def fetch_state(self, step: int) -> dict:
+        """The plane's whole worker state on the host, counted."""
+        with tracing.span("service.fetch", step):
+            self.fetches.add(tracing.nbytes(self.lanes.worker))
+            return _engine._fetch_batch_state(self.lanes.worker)
 
     def occupied_count(self) -> int:
         return int(self.lanes.occupied().sum())
@@ -458,6 +468,10 @@ class SolveService:
         s["faults_recovered"] = inj.faults_recovered if inj is not None else 0
         s["retries"] = inj.retries if inj is not None else 0
         s["lanes_shed"] = sum(p.shed for p in self._planes.values())
+        s["host_fetches"] = sum(p.fetches.count for p in self._planes.values())
+        s["host_fetch_bytes"] = sum(
+            p.fetches.bytes for p in self._planes.values()
+        )
         return s
 
     def cache_stats(self) -> dict:
@@ -499,11 +513,16 @@ class SolveService:
         )
         planes_meta = []
         for pi, (key, plane) in enumerate(self._planes.items()):
-            ck.arrays.update(lane_state_to_flat(plane.lanes, f"plane{pi}/lanes"))
+            lanes = plane.lanes
+            plane.fetches.add(
+                tracing.nbytes((lanes.worker, lanes.done, lanes.rounds))
+            )
+            plane.fetches.add(tracing.nbytes(plane.datas))
+            ck.arrays.update(lane_state_to_flat(lanes, f"plane{pi}/lanes"))
             ck.arrays.update(_ckpt.data_to_flat(plane.datas, f"plane{pi}/datas"))
             if plane.use_fpt:
                 ck.arrays[f"plane{pi}/fpt_bounds"] = np.asarray(
-                    jax.device_get(plane.fpt_bounds)
+                    plane.fetches.get(plane.fpt_bounds)
                 )
             for lane, sp in enumerate(plane.spillers):
                 if sp is not None:
@@ -659,6 +678,11 @@ class SolveService:
                 tenant_occ[req.tenant] = tenant_occ.get(req.tenant, 0) + 1
 
     def _admit_into(self, plane: _LivePlane, lane: int, req: SolveRequest) -> None:
+        with tracing.span("service.admit", req.ticket):
+            self._swap_in_request(plane, lane, req)
+        plane.fetch_mark[lane] = (plane.fetches.count, plane.fetches.bytes)
+
+    def _swap_in_request(self, plane: _LivePlane, lane: int, req: SolveRequest) -> None:
         cfg, spec, g = self.config, self.spec, req.g
         # the solo pad for this n must match the plane's (true for the
         # native record schema; a problem with n-sized record extras under
@@ -746,6 +770,15 @@ class SolveService:
             ledger[2] += 1
 
     def _step_plane(self, plane: _LivePlane) -> list:
+        """One chunk of ``plane``, under the host span ``service.step`` (and
+        ``service.fetch`` / ``service.admit`` / ``service.retire`` within
+        the service's steps: admit and retire carry the ticket, step and
+        fetch the step's own id)."""
+        step = tracing.new_request_id()
+        with tracing.span("service.step", step):
+            return self._run_plane_chunk(plane, step)
+
+    def _run_plane_chunk(self, plane: _LivePlane, step: int) -> list:
         inj = self.injector
         occupied_before = plane.lanes.occupied()
         self._stats["chunk_calls"] += 1
@@ -783,9 +816,11 @@ class SolveService:
             plane.lanes = lane_write_back(
                 plane.lanes, lane, worker, done_snap, rounds_snap
             )
-        done_h, rounds_h = map(
-            np.asarray, jax.device_get((plane.lanes.done, plane.lanes.rounds))
-        )
+        with tracing.span("service.fetch", step):
+            done_h, rounds_h = map(
+                np.asarray,
+                plane.fetches.get((plane.lanes.done, plane.lanes.rounds)),
+            )
         done_h = np.array(done_h)
 
         # stall watchdog: an occupied, unfinished lane whose round counter
@@ -832,7 +867,7 @@ class SolveService:
             # not retired (an FPT bound hit finishes regardless)
             from repro.core.superstep import lane_resume
 
-            hot_h = np.array(jax.device_get(hot))
+            hot_h = np.array(plane.fetches.get(hot))
             best_h = bounds_h = None
             for lane in np.flatnonzero(occupied):
                 sp = plane.spillers[lane]
@@ -845,13 +880,18 @@ class SolveService:
                 if bool(done_h[lane]) and plane.use_fpt:
                     if best_h is None:
                         best_h = np.asarray(
-                            jax.device_get(plane.lanes.worker.best_val)
+                            plane.fetches.get(plane.lanes.worker.best_val)
                         )[:, 0]
                         bounds_h = np.asarray(
-                            jax.device_get(plane.fpt_bounds)
+                            plane.fetches.get(plane.fpt_bounds)
                         )
                     if int(best_h[lane]) <= int(bounds_h[lane]):
                         continue
+                f = plane.lanes.worker.frontier  # the pump reads one lane's pool
+                plane.fetches.add(
+                    tracing.nbytes((f.masks, f.sols, f.depths, f.active))
+                    // plane.lanes.num_lanes
+                )
                 plane.lanes, hot_lane = sp.pump_lane(plane.lanes, int(lane))
                 hot_h[lane] = hot_lane
                 if bool(done_h[lane]) and int(hot_lane.sum()) > 0:
@@ -882,63 +922,67 @@ class SolveService:
         if len(finished) == 0 and not over_budget:
             return []
 
-        host = _engine._fetch_batch_state(plane.lanes.worker)
+        host = plane.fetch_state(step)
         completed = []
         for lane in list(finished) + list(over_budget):
             lane = int(lane)
             req = plane.requests[lane]
-            evicted = lane not in finished
-            r = _engine._extract_result(
-                host,
-                lane,
-                self.spec,
-                req.g,
-                int(rounds_h[lane]),
-                now - plane.admit_s[lane],
-                mode=self.config.mode,
-                k=req.k,
-                num_workers=self.config.num_workers,
-                packed_status=self.config.packed_status,
-            )
-            res = from_engine_result(r, problem=self.spec.name, backend="spmd")
-            sp = plane.spillers[lane]
-            if sp is not None:
-                res.stats.spilled_tasks = sp.spilled_total
-                res.stats.readmitted_tasks = sp.readmitted_total
-                res.stats.cold_bytes_peak = sp.cold_bytes_peak
-            fi, fr, fq = self._req_faults.pop(req.ticket, (0, 0, 0))
-            res.stats.service = ServiceStats(
-                lane=lane,
-                plane=str(plane.key),
-                wait_s=plane.admit_s[lane] - req.submit_s,
-                residency_s=now - plane.admit_s[lane],
-                deadline_hit=(
-                    evicted
-                    and req.deadline is not None
-                    and lane not in over_wall
-                    and lane not in timed_out
-                ),
-                wall_deadline_hit=lane in over_wall,
-                faults_injected=fi,
-                faults_recovered=fr,
-                lanes_quarantined=fq,
-                retries=sp.delivery_retries if sp is not None else 0,
-            )
-            if lane in timed_out:
-                self._results[req.ticket] = SolveTimeout(
-                    req.ticket, result=res, waited_s=now - req.submit_s
+            with tracing.span("service.retire", req.ticket):
+                evicted = lane not in finished
+                r = _engine._extract_result(
+                    host,
+                    lane,
+                    self.spec,
+                    req.g,
+                    int(rounds_h[lane]),
+                    now - plane.admit_s[lane],
+                    mode=self.config.mode,
+                    k=req.k,
+                    num_workers=self.config.num_workers,
+                    packed_status=self.config.packed_status,
                 )
-                self._stats["timed_out"] += 1
-            else:
-                self._results[req.ticket] = res
-            completed.append(req.ticket)
-            self._stats["completed"] += 1
-            self._stats["evicted"] += int(evicted)
-            self._stats["wait_s_total"] += plane.admit_s[lane] - req.submit_s
-            self._stats["residency_s_total"] += now - plane.admit_s[lane]
-            plane.lanes = lane_retire(plane.lanes, lane)
-            plane.requests[lane] = None
-            plane.spillers[lane] = None
+                res = from_engine_result(r, problem=self.spec.name, backend="spmd")
+                mark_count, mark_bytes = plane.fetch_mark[lane]
+                res.stats.host_fetches = plane.fetches.count - mark_count
+                res.stats.host_fetch_bytes = plane.fetches.bytes - mark_bytes
+                sp = plane.spillers[lane]
+                if sp is not None:
+                    res.stats.spilled_tasks = sp.spilled_total
+                    res.stats.readmitted_tasks = sp.readmitted_total
+                    res.stats.cold_bytes_peak = sp.cold_bytes_peak
+                fi, fr, fq = self._req_faults.pop(req.ticket, (0, 0, 0))
+                res.stats.service = ServiceStats(
+                    lane=lane,
+                    plane=str(plane.key),
+                    wait_s=plane.admit_s[lane] - req.submit_s,
+                    residency_s=now - plane.admit_s[lane],
+                    deadline_hit=(
+                        evicted
+                        and req.deadline is not None
+                        and lane not in over_wall
+                        and lane not in timed_out
+                    ),
+                    wall_deadline_hit=lane in over_wall,
+                    faults_injected=fi,
+                    faults_recovered=fr,
+                    lanes_quarantined=fq,
+                    retries=sp.delivery_retries if sp is not None else 0,
+                )
+                if lane in timed_out:
+                    self._results[req.ticket] = SolveTimeout(
+                        req.ticket, result=res, waited_s=now - req.submit_s
+                    )
+                    self._stats["timed_out"] += 1
+                else:
+                    self._results[req.ticket] = res
+                completed.append(req.ticket)
+                self._stats["completed"] += 1
+                self._stats["evicted"] += int(evicted)
+                self._stats["wait_s_total"] += plane.admit_s[lane] - req.submit_s
+                self._stats["residency_s_total"] += now - plane.admit_s[lane]
+                plane.lanes = lane_retire(plane.lanes, lane)
+                plane.requests[lane] = None
+                plane.spillers[lane] = None
         return completed
 
 

@@ -43,6 +43,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.superstep import (
+    REDUCE_COUNTERS,
     WorkerState,
     build_chunk_fn,
     make_worker_state,
@@ -91,6 +92,17 @@ class EngineResult:
     spilled_tasks: int = 0
     readmitted_tasks: int = 0
     cold_bytes_peak: int = 0
+    # the explore reduction's work, summed over workers (WorkerState's
+    # reduce_* counters; 0 without a reduction)
+    reduce_lane_sweeps: int = 0
+    reduce_worker_sweeps: int = 0
+    reduce_fires_rule1: int = 0
+    reduce_fires_rule2: int = 0
+    reduce_fires_rule3: int = 0
+    # device-to-host fetches of the host loop while this instance was on the
+    # plane, and their bytes (repro.tracing.Fetches); set by the host drivers
+    host_fetches: int = 0
+    host_fetch_bytes: int = 0
 
 
 def _scatter_startup(
@@ -322,6 +334,7 @@ def _extract_result(
         transfer_rounds=transfer_rounds,
         transfer_bytes_total=4 * payload_words,
         transfer_bytes_per_round=4 * payload_words / max(rounds, 1),
+        **{name: int(host_state[name][lane].sum()) for name in REDUCE_COUNTERS},
     )
 
 
@@ -336,6 +349,7 @@ def _fetch_batch_state(state: WorkerState) -> dict:
         "dropped": np.asarray(s.frontier.dropped),
         "transfer_rounds": np.asarray(s.transfer_rounds),
         "payload_words": np.asarray(s.payload_words),
+        **{name: np.asarray(getattr(s, name)) for name in REDUCE_COUNTERS},
     }
 
 

@@ -115,6 +115,14 @@ class WorkerState(NamedTuple):
     # never has to sync for stats (replicated: same value on every worker)
     transfer_rounds: jnp.ndarray  # () int32 -- rounds that ran the data plane
     payload_words: jnp.ndarray  # () int32 -- u32 words moved by the data plane
+    # the explore reduction's work (ExpandResult.work; 0 for a plugin
+    # without a reduction and on the reference explore path).  Counters,
+    # not trajectory: a checkpoint without them loads them as 0.
+    reduce_lane_sweeps: jnp.ndarray  # () int32 -- sweeps of expanded lanes
+    reduce_worker_sweeps: jnp.ndarray  # () int32 -- per step, max over lanes
+    reduce_fires_rule1: jnp.ndarray  # () int32
+    reduce_fires_rule2: jnp.ndarray  # () int32
+    reduce_fires_rule3: jnp.ndarray  # () int32
 
     @property
     def overflow_count(self) -> jnp.ndarray:
@@ -138,12 +146,29 @@ def make_worker_state(capacity: int, W: int, initial_best: int) -> WorkerState:
         rounds=z,
         transfer_rounds=z,
         payload_words=z,
+        reduce_lane_sweeps=z,
+        reduce_worker_sweeps=z,
+        reduce_fires_rule1=z,
+        reduce_fires_rule2=z,
+        reduce_fires_rule3=z,
     )
+
+
+# the reduction's counters by name: results sum them over workers, and a
+# checkpoint may lack them (they are not trajectory)
+REDUCE_COUNTERS = (
+    "reduce_lane_sweeps",
+    "reduce_worker_sweeps",
+    "reduce_fires_rule1",
+    "reduce_fires_rule2",
+    "reduce_fires_rule3",
+)
 
 
 # -- phase 1: exploration ------------------------------------------------------
 
 
+@jax.named_scope("explore")
 def _explore_one_round(
     problem: BranchingProblem,
     data: ProblemData,
@@ -163,16 +188,21 @@ def _explore_one_round(
     full-capacity top_k pop); the fused path pops via the cheap depth-major
     selection and expands through the plugin's one-pass ``expand_tasks``.
     Both produce bit-identical states.
+
+    Its operations carry the scopes ``explore/pop``, ``explore/expand`` and
+    ``explore/push`` (the best update sits in ``explore`` alone).
     """
-    if explore_impl == "fused":
-        f, masks, sols, depths, valid = pop_deepest_cheap(state.frontier, lanes)
-        expand = resolve_expand(problem)
-    else:
-        f, masks, sols, depths, valid = pop_deepest(state.frontier, lanes)
-        # ALWAYS the composed per-task callables — one source of truth with
-        # the fused path's default, so the two can never desynchronize
-        expand = compose_expand_tasks(problem)
-    ex = expand(data, masks, sols)
+    with jax.named_scope("pop"):
+        if explore_impl == "fused":
+            f, masks, sols, depths, valid = pop_deepest_cheap(state.frontier, lanes)
+            expand = resolve_expand(problem)
+        else:
+            f, masks, sols, depths, valid = pop_deepest(state.frontier, lanes)
+            # ALWAYS the composed per-task callables — one source of truth
+            # with the fused path's default, so the two can never desynchronize
+            expand = compose_expand_tasks(problem)
+    with jax.named_scope("expand"):
+        ex = expand(data, masks, sols)
     bounds, res = ex.bound, ex.step
     left_bound, right_bound = ex.left_bound, ex.right_bound
 
@@ -202,14 +232,29 @@ def _explore_one_round(
     all_sols = jnp.concatenate([res.left_sol, res.right_sol], axis=0)
     all_depths = jnp.concatenate([cdepth, cdepth], axis=0)
     all_valid = jnp.concatenate([lvalid, rvalid], axis=0)
-    f = push_many(f, all_masks, all_sols, all_depths, all_valid)
+    with jax.named_scope("push"):
+        f = push_many(f, all_masks, all_sols, all_depths, all_valid)
 
-    return state._replace(
+    state = state._replace(
         frontier=f,
         best_val=new_best,
         local_best_val=new_local,
         best_sol=new_sol,
         nodes_expanded=state.nodes_expanded + valid.sum().astype(jnp.int32),
+    )
+    if ex.work is None:
+        return state
+    # the lanes sweep in lockstep: the worker's loop runs as often as its
+    # slowest lane, popped or not
+    sweeps = jnp.where(valid, ex.work.sweeps, 0).sum().astype(jnp.int32)
+    fires = jnp.where(valid[:, None], ex.work.fires, 0).sum(axis=0).astype(jnp.int32)
+    return state._replace(
+        reduce_lane_sweeps=state.reduce_lane_sweeps + sweeps,
+        reduce_worker_sweeps=state.reduce_worker_sweeps
+        + ex.work.sweeps.max().astype(jnp.int32),
+        reduce_fires_rule1=state.reduce_fires_rule1 + fires[0],
+        reduce_fires_rule2=state.reduce_fires_rule2 + fires[1],
+        reduce_fires_rule3=state.reduce_fires_rule3 + fires[2],
     )
 
 
@@ -332,6 +377,10 @@ def superstep(
                             callables + full-capacity top_k.  Bit-identical
                             traces (see :data:`EXPLORE_IMPLS`).
 
+    Its operations carry the scopes ``explore/...`` (see
+    :func:`_explore_one_round`), ``center`` (status gather, best pmin,
+    matching), ``transfer`` (the data plane) and ``termination``.
+
     Returns (state, done) where done is the exact global quiescence flag.
     """
     if transfer_impl not in TRANSFER_IMPLS:
@@ -358,41 +407,42 @@ def superstep(
         problem, data, state, steps_per_round, lanes, explore_impl
     )
 
-    # 2. control plane through the "center" + 5. best-value broadcast
-    pending = state.frontier.pending()
-    top_depth = state.frontier.top_priority_depth()
-    if packed_status:
-        # one i32 per worker: pending (15b) | clamped depth (16b)
-        word = (jnp.clip(pending, 0, 0x7FFF) << 16) | jnp.clip(
-            top_depth, 0, 0xFFFF
-        )
-        table_w = jax.lax.all_gather(word, axis_name)  # (P,)
-        pend_t = table_w >> 16
-        depth_t = table_w & 0xFFFF
-        global_best = jax.lax.pmin(
-            jnp.minimum(state.local_best_val, state.best_val), axis_name
-        )
-    else:
-        my_status = jnp.stack([pending, top_depth, state.local_best_val])
-        table = jax.lax.all_gather(my_status, axis_name)  # (P, 3)
-        pend_t, depth_t = table[:, 0], table[:, 1]
-        global_best = jnp.minimum(table[:, 2].min(), state.best_val)
-    state = state._replace(best_val=global_best)
+    with jax.named_scope("center"):
+        # 2. control plane through the "center" + 5. best-value broadcast
+        pending = state.frontier.pending()
+        top_depth = state.frontier.top_priority_depth()
+        if packed_status:
+            # one i32 per worker: pending (15b) | clamped depth (16b)
+            word = (jnp.clip(pending, 0, 0x7FFF) << 16) | jnp.clip(
+                top_depth, 0, 0xFFFF
+            )
+            table_w = jax.lax.all_gather(word, axis_name)  # (P,)
+            pend_t = table_w >> 16
+            depth_t = table_w & 0xFFFF
+            global_best = jax.lax.pmin(
+                jnp.minimum(state.local_best_val, state.best_val), axis_name
+            )
+        else:
+            my_status = jnp.stack([pending, top_depth, state.local_best_val])
+            table = jax.lax.all_gather(my_status, axis_name)  # (P, 3)
+            pend_t, depth_t = table[:, 0], table[:, 1]
+            global_best = jnp.minimum(table[:, 2].min(), state.best_val)
+        state = state._replace(best_val=global_best)
 
-    # 3. replicated center matching
-    P = pend_t.shape[0]
-    me = jax.lax.axis_index(axis_name).astype(jnp.int32)
-    send_to, recv_from = match_idle_to_donors(
-        pend_t, depth_t, policy_priority, state.rounds
-    )
-    n_match = (send_to >= 0).sum()
-    # records each donor actually ships (>=1 when matched: pending >= 2);
-    # replicated, so donor AND receiver count the block identically.
-    n_don = jnp.where(
-        send_to >= 0,
-        jnp.minimum(jnp.int32(donate_k), pend_t - 1),
-        jnp.int32(0),
-    )  # (P,)
+        # 3. replicated center matching
+        P = pend_t.shape[0]
+        me = jax.lax.axis_index(axis_name).astype(jnp.int32)
+        send_to, recv_from = match_idle_to_donors(
+            pend_t, depth_t, policy_priority, state.rounds
+        )
+        n_match = (send_to >= 0).sum()
+        # records each donor actually ships (>=1 when matched: pending >= 2);
+        # replicated, so donor AND receiver count the block identically.
+        n_don = jnp.where(
+            send_to >= 0,
+            jnp.minimum(jnp.int32(donate_k), pend_t - 1),
+            jnp.int32(0),
+        )  # (P,)
 
     # 4. data plane: donor pops its shallowest block; record row =
     #    (mask, sol, depth[, pad])
@@ -447,17 +497,19 @@ def superstep(
             payload_words=state.payload_words + moved_words,
         )
 
-    if skip_empty_transfer:
-        # n_match derives from the replicated table: every worker takes the
-        # same branch, so the collective inside the cond is safe.
-        state = jax.lax.cond(n_match > 0, do_transfer, lambda s: s, state)
-    else:
-        state = do_transfer(state)
+    with jax.named_scope("transfer"):
+        if skip_empty_transfer:
+            # n_match derives from the replicated table: every worker takes
+            # the same branch, so the collective inside the cond is safe.
+            state = jax.lax.cond(n_match > 0, do_transfer, lambda s: s, state)
+        else:
+            state = do_transfer(state)
     state = state._replace(rounds=state.rounds + 1)
 
     # exact termination: nothing pending anywhere after the transfer phase
-    total_pending = jax.lax.psum(state.frontier.pending(), axis_name)
-    done = total_pending == 0
+    with jax.named_scope("termination"):
+        total_pending = jax.lax.psum(state.frontier.pending(), axis_name)
+        done = total_pending == 0
     return state, done
 
 
@@ -939,8 +991,10 @@ def worker_state_from_flat(flat: dict, prefix: str = "worker") -> WorkerState:
     rest = {
         name: jnp.asarray(flat[f"{prefix}.{name}"])
         for name in WorkerState._fields
-        if name != "frontier"
+        if name != "frontier" and f"{prefix}.{name}" in flat
     }
+    for name in REDUCE_COUNTERS:  # a checkpoint written before them
+        rest.setdefault(name, jnp.zeros_like(rest["nodes_expanded"]))
     return WorkerState(frontier=frontier, **rest)
 
 

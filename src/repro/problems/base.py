@@ -96,6 +96,18 @@ class ExpandResult(NamedTuple):
     step: BranchStep  # batched: every field has a leading (L,) axis
     left_bound: jnp.ndarray  # (L,) int32 -- child_bound of the left child
     right_bound: jnp.ndarray  # (L,) int32 -- child_bound of the right child
+    # per-lane work of the plugin's reduction, counted on device; None for a
+    # plugin without one (the engine's reduction counters then stay 0)
+    work: Optional["ReduceWork"] = None
+
+
+class ReduceWork(NamedTuple):
+    """What a reduction to fixpoint did on each lane of a batch: the sweeps
+    its loop ran (the last one changes nothing) and how often each of up to
+    three rules fired.  Read by the engine's ``reduce_*`` counters."""
+
+    sweeps: jnp.ndarray  # (L,) int32
+    fires: jnp.ndarray  # (L, 3) int32 -- firings of rules 1, 2, 3
 
 
 # -- packed-bitset primitives (problem-agnostic device ops) --------------------

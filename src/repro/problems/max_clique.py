@@ -72,21 +72,23 @@ def expand_tasks(data: ProblemData, masks, sols) -> ExpandResult:
     quantity is bit-identical to the composed path (property-tested).
     """
     W = data.adj.shape[1]
-    deg, pc_mask, pc_sol = expand_stats_batch(data, masks, sols)  # (L,n),(L,),(L,)
-    task_bound_v = -(pc_sol + pc_mask)
-    u = jnp.argmax(deg, axis=1).astype(jnp.int32)  # (L,)
-    deg_u = deg.max(axis=1)  # == deg[u] (the argmax row max), one reduce
-    u_bit = jax.vmap(lambda v: single_bit(v, W))(u)  # (L, W)
-    nb = data.adj[u] & masks  # (L, W)
-    step = BranchStep(
-        left_mask=nb,
-        left_sol=sols | u_bit,
-        right_mask=masks & ~u_bit,
-        right_sol=sols,
-        is_terminal=pc_mask == 0,
-        terminal_sol=sols,
-        terminal_value=-pc_sol,
-    )
+    with jax.named_scope("degrees"):
+        deg, pc_mask, pc_sol = expand_stats_batch(data, masks, sols)  # (L,n),(L,),(L,)
+        task_bound_v = -(pc_sol + pc_mask)
+    with jax.named_scope("pivot"):
+        u = jnp.argmax(deg, axis=1).astype(jnp.int32)  # (L,)
+        deg_u = deg.max(axis=1)  # == deg[u] (the argmax row max), one reduce
+        u_bit = jax.vmap(lambda v: single_bit(v, W))(u)  # (L, W)
+        nb = data.adj[u] & masks  # (L, W)
+        step = BranchStep(
+            left_mask=nb,
+            left_sol=sols | u_bit,
+            right_mask=masks & ~u_bit,
+            right_sol=sols,
+            is_terminal=pc_mask == 0,
+            terminal_sol=sols,
+            terminal_value=-pc_sol,
+        )
     return ExpandResult(
         bound=task_bound_v,
         step=step,

@@ -34,6 +34,7 @@ from repro.problems.base import (  # noqa: F401  (re-exported public API)
     BranchStep,
     ExpandResult,
     ProblemData,
+    ReduceWork,
     degrees,
     degrees_batch,
     edge_count,
@@ -76,7 +77,8 @@ def _first_vertex(cond: jnp.ndarray, n_total: int) -> jnp.ndarray:
 
 
 def _reduce_step(problem: ProblemData, mask, sol_mask):
-    """One reduction sweep.  Returns (mask, sol_mask, changed)."""
+    """One reduction sweep.  Returns (mask, sol_mask, rule): the rule that
+    fired (1, 2 or 3; () int32), 0 when none did."""
     n_total, W = problem.adj.shape
     deg = degrees(problem, mask)
     inside = deg >= 0
@@ -115,29 +117,59 @@ def _reduce_step(problem: ProblemData, mask, sol_mask):
     # Priority: rule 1 > rule 2 > rule 3 (mirrors the host reference).
     new_mask = jnp.where(any_iso, mask_r1, jnp.where(has_u2, mask_r2, jnp.where(has_u3, mask_r3, mask)))
     new_sol = jnp.where(any_iso, sol_mask, jnp.where(has_u2, sol_r2, jnp.where(has_u3, sol_r3, sol_mask)))
-    changed = any_iso | has_u2 | has_u3
-    return new_mask, new_sol, changed
+    rule = jnp.where(any_iso, 1, jnp.where(has_u2, 2, jnp.where(has_u3, 3, 0)))
+    return new_mask, new_sol, rule.astype(jnp.int32)
 
 
-def reduce_instance(problem: ProblemData, mask, sol_mask):
-    """Apply rules 1-3 to fixpoint (bounded while_loop)."""
+def _reduce_counted(problem: ProblemData, mask, sol_mask):
+    """Apply rules 1-3 to fixpoint (bounded while_loop); returns (mask,
+    sol_mask, :class:`ReduceWork`): the sweeps the loop ran, its last one
+    (which changes nothing) included, and the (3,) firings of each rule.
+    Every firing removes a vertex, so the bound of n + 1 sweeps never cuts
+    the loop short and sweeps == fires.sum() + 1."""
 
     def cond(state):
-        _, _, changed, it = state
+        _, _, changed, it, _ = state
         return changed & (it < problem.adj.shape[0] + 1)
 
     def body(state):
-        m, s, _, it = state
-        m2, s2, ch = _reduce_step(problem, m, s)
-        return (m2, s2, ch, it + 1)
+        m, s, _, it, fires = state
+        with jax.named_scope("sweep"):
+            m2, s2, rule = _reduce_step(problem, m, s)
+            fires = fires + (jnp.arange(1, 4) == rule).astype(jnp.int32)
+        return (m2, s2, rule > 0, it + 1, fires)
 
     # initial `changed` is derived from mask (always True) so its varying-
     # manual-axes match the body output under shard_map (see JAX scan-vma).
     changed0 = popcount(mask) >= 0
-    mask, sol_mask, _, _ = jax.lax.while_loop(
-        cond, body, (mask, sol_mask, changed0, jnp.int32(0))
-    )
+    with jax.named_scope("reduce"):
+        mask, sol_mask, _, sweeps, fires = jax.lax.while_loop(
+            cond, body,
+            (mask, sol_mask, changed0, jnp.int32(0), jnp.zeros(3, jnp.int32)),
+        )
+    return mask, sol_mask, ReduceWork(sweeps=sweeps, fires=fires)
+
+
+def reduce_instance(problem: ProblemData, mask, sol_mask):
+    """Apply rules 1-3 to fixpoint (bounded while_loop)."""
+    mask, sol_mask, _ = _reduce_counted(problem, mask, sol_mask)
     return mask, sol_mask
+
+
+_REDUCE_INSTANCE = reduce_instance
+
+
+def _reduce_lanes(problem: ProblemData, masks, sols):
+    """:func:`reduce_instance` over an (L, W) lane batch, with each lane's
+    :class:`ReduceWork`.  ``reduce_instance`` stays the module's override
+    point: a replaced one (a variant, or a planted fault) runs as it is,
+    and its work is not counted (None)."""
+    if reduce_instance is not _REDUCE_INSTANCE:
+        rmasks, rsols = jax.vmap(
+            lambda m, s: reduce_instance(problem, m, s)
+        )(masks, sols)
+        return rmasks, rsols, None
+    return jax.vmap(lambda m, s: _reduce_counted(problem, m, s))(masks, sols)
 
 
 # -- branching (paper Algorithm 8 lines 7-11) ----------------------------------
@@ -186,31 +218,33 @@ def expand_tasks(problem: ProblemData, masks, sols) -> ExpandResult:
     composed per-task callables (property-tested).
     """
     W = problem.adj.shape[1]
-    deg0 = degrees_batch(problem, masks)  # (L, n)
-    bound = popcount(sols) + jax.vmap(lower_bound)(deg0)  # (L,)
-    rmasks, rsols = jax.vmap(
-        lambda m, s: reduce_instance(problem, m, s)
-    )(masks, sols)
-    deg = degrees_batch(problem, rmasks)  # (L, n)
-    maxdeg = deg.max(axis=1)  # also == deg[u], so it feeds the right bound
-    u = jnp.argmax(deg, axis=1).astype(jnp.int32)
-    u_bit = jax.vmap(lambda v: single_bit(v, W))(u)
-    nb = problem.adj[u] & rmasks
-    pc_rsol = popcount(rsols)  # (L,)
-    step = BranchStep(
-        left_mask=rmasks & ~u_bit,
-        left_sol=rsols | u_bit,
-        right_mask=rmasks & ~(nb | u_bit),
-        right_sol=rsols | nb,
-        is_terminal=maxdeg <= 0,
-        terminal_sol=rsols,
-        terminal_value=pc_rsol,
-    )
+    with jax.named_scope("degrees"):
+        deg0 = degrees_batch(problem, masks)  # (L, n)
+        bound = popcount(sols) + jax.vmap(lower_bound)(deg0)  # (L,)
+    rmasks, rsols, work = _reduce_lanes(problem, masks, sols)
+    with jax.named_scope("degrees"):
+        deg = degrees_batch(problem, rmasks)  # (L, n)
+    with jax.named_scope("pivot"):
+        maxdeg = deg.max(axis=1)  # also == deg[u], so it feeds the right bound
+        u = jnp.argmax(deg, axis=1).astype(jnp.int32)
+        u_bit = jax.vmap(lambda v: single_bit(v, W))(u)
+        nb = problem.adj[u] & rmasks
+        pc_rsol = popcount(rsols)  # (L,)
+        step = BranchStep(
+            left_mask=rmasks & ~u_bit,
+            left_sol=rsols | u_bit,
+            right_mask=rmasks & ~(nb | u_bit),
+            right_sol=rsols | nb,
+            is_terminal=maxdeg <= 0,
+            terminal_sol=rsols,
+            terminal_value=pc_rsol,
+        )
     return ExpandResult(
         bound=bound,
         step=step,
         left_bound=pc_rsol + 1,
         right_bound=pc_rsol + maxdeg,
+        work=work,
     )
 
 

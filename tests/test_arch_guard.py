@@ -240,6 +240,8 @@ SOLVE_STATS_FIELDS = [
     "cold_bytes_peak",
     "control_bytes_per_round",
     "failed_requests",
+    "host_fetch_bytes",
+    "host_fetches",
     "max_depth",
     "msg_bytes",
     "msg_count",
@@ -247,6 +249,11 @@ SOLVE_STATS_FIELDS = [
     "overflow_count",
     "pruned",
     "readmitted_tasks",
+    "reduce_fires_rule1",
+    "reduce_fires_rule2",
+    "reduce_fires_rule3",
+    "reduce_lane_sweeps",
+    "reduce_worker_sweeps",
     "resumed_from",
     "service",
     "solutions",

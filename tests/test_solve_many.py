@@ -148,6 +148,11 @@ def _hand_built_batch(masks_spec, P=4, cap=8, W=1, n=16):
         rounds=z,
         transfer_rounds=z,
         payload_words=z,
+        reduce_lane_sweeps=z,
+        reduce_worker_sweeps=z,
+        reduce_fires_rule1=z,
+        reduce_fires_rule2=z,
+        reduce_fires_rule3=z,
     )
     v = np.arange(n, dtype=np.int32)
     problems = VCProblem(
