@@ -40,52 +40,52 @@ def _first_bit(words: np.ndarray) -> int:
     return -1
 
 
-def reduce_instance(
+def reduce_sweep(
     g: BitGraph, mask: np.ndarray, sol_mask: np.ndarray
-) -> tuple[np.ndarray, np.ndarray]:
-    """Apply rules 1-3 iteratively until the instance stops changing.
+) -> tuple[np.ndarray, np.ndarray, int]:
+    """One sweep: the first rule that applies fires once, on its lowest
+    vertex.  Returns new (mask, sol_mask) and the rule that fired (1, 2 or
+    3), 0 when none did.
 
     Rule 1: drop isolated vertices.
     Rule 2: for a degree-1 vertex u with neighbor v, add v to S, drop u, v.
     Rule 3: for a degree-2 vertex u with adjacent neighbors v, w, add v and w
             to S, drop u, v, w.
     """
-    mask = mask.copy()
-    sol_mask = sol_mask.copy()
-    changed = True
-    while changed:
-        changed = False
-        deg = g.degrees(mask)
-        inside = deg >= 0
-        # Rule 1 (batch-safe: removals never conflict)
-        iso = inside & (deg == 0)
-        if iso.any():
-            from repro.graphs.bitgraph import pack_masks
+    deg = g.degrees(mask)
+    inside = deg >= 0
+    # Rule 1 (batch-safe: removals never conflict)
+    iso = inside & (deg == 0)
+    if iso.any():
+        from repro.graphs.bitgraph import pack_masks
 
-            mask &= ~pack_masks(iso)
-            changed = True
-            continue
-        # Rule 2 (one vertex per sweep; batching can over-add on isolated edges)
-        ones = np.nonzero(inside & (deg == 1))[0]
-        if len(ones):
-            u = int(ones[0])
-            nb = g.adj[u] & mask
-            sol_mask |= nb
-            mask &= ~(nb | single_bit(u, g.W))
-            changed = True
-            continue
-        # Rule 3
-        twos = np.nonzero(inside & (deg == 2))[0]
-        for u in twos:
-            nb = g.adj[int(u)] & mask
-            v = _first_bit(nb)
-            rest = nb & ~single_bit(v, g.W)
-            w = _first_bit(rest)
-            if g.adj[v][w // 32] & np.uint32(1 << (w % 32)):  # v-w edge exists
-                sol_mask |= nb
-                mask &= ~(nb | single_bit(int(u), g.W))
-                changed = True
-                break
+        return mask & ~pack_masks(iso), sol_mask.copy(), 1
+    # Rule 2 (one vertex per sweep; batching can over-add on isolated edges)
+    ones = np.nonzero(inside & (deg == 1))[0]
+    if len(ones):
+        u = int(ones[0])
+        nb = g.adj[u] & mask
+        return mask & ~(nb | single_bit(u, g.W)), sol_mask | nb, 2
+    # Rule 3
+    twos = np.nonzero(inside & (deg == 2))[0]
+    for u in twos:
+        nb = g.adj[int(u)] & mask
+        v = _first_bit(nb)
+        rest = nb & ~single_bit(v, g.W)
+        w = _first_bit(rest)
+        if g.adj[v][w // 32] & np.uint32(1 << (w % 32)):  # v-w edge exists
+            return mask & ~(nb | single_bit(int(u), g.W)), sol_mask | nb, 3
+    return mask.copy(), sol_mask.copy(), 0
+
+
+def reduce_instance(
+    g: BitGraph, mask: np.ndarray, sol_mask: np.ndarray
+) -> tuple[np.ndarray, np.ndarray]:
+    """Apply rules 1-3 (:func:`reduce_sweep`) until the instance stops
+    changing."""
+    rule = -1
+    while rule:
+        mask, sol_mask, rule = reduce_sweep(g, mask, sol_mask)
     return mask, sol_mask
 
 

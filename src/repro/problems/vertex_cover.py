@@ -11,10 +11,9 @@ for compatibility).
 All control flow is `jax.lax` (while_loop / select) so the ops compose into
 the SPMD superstep engine (`repro.core.superstep`) and into the Pallas
 bitset kernels (`repro.kernels.bitset_ops`, which accelerates `degrees`).
-Semantics match the host reference exactly (tests assert equality), with one
-deliberate exception: rule application order inside `reduce_instance` may pick
-a different (equally valid) vertex — both preserve at least one optimal
-cover, so terminal best values are identical.
+Semantics match the host reference exactly (tests assert equality):
+`reduce_instance` fires the same rule on the same lowest qualifying vertex
+as `sequential.reduce_sweep`, sweep for sweep, and ends in the same masks.
 
 ``SPEC`` at the bottom is the :class:`~repro.problems.base.BranchingProblem`
 plugin registered as ``"vertex_cover"``.
@@ -76,6 +75,26 @@ def _first_vertex(cond: jnp.ndarray, n_total: int) -> jnp.ndarray:
     return idx.min()
 
 
+def _first_neighbour(rows: jnp.ndarray) -> jnp.ndarray:
+    """Lowest set bit of each packed (n, W) row, word by word: (n,) int32,
+    n for an empty row."""
+    n_total, W = rows.shape
+    low = rows & (~rows + jnp.uint32(1))  # each word's lowest set bit alone
+    bit = WORD_BITS - 1 - jax.lax.clz(low).astype(jnp.int32)
+    first = jnp.arange(W, dtype=jnp.int32) * WORD_BITS + bit
+    return jnp.where(rows != 0, first, n_total).min(axis=1)
+
+
+def _ends_adjacent(adj: jnp.ndarray, rows: jnp.ndarray) -> jnp.ndarray:
+    """Whether the two neighbours of each row of ``rows`` (packed neighbour
+    rows inside the mask) are adjacent, (n,) bool; meaningful for rows of
+    exactly two neighbours.  The first neighbour's row of the shared ``adj``
+    meets such a row in the other neighbour's bit alone, if at all (graphs
+    have no self-loops).  A row lookup: O(n W) words, no n x n table."""
+    fc = jnp.clip(_first_neighbour(rows), 0, adj.shape[0] - 1)
+    return (adj[fc] & rows).any(axis=-1)
+
+
 def _reduce_step(problem: ProblemData, mask, sol_mask):
     """One reduction sweep.  Returns (mask, sol_mask, rule): the rule that
     fired (1, 2 or 3; () int32), 0 when none did."""
@@ -98,14 +117,8 @@ def _reduce_step(problem: ProblemData, mask, sol_mask):
     mask_r2 = mask & ~(nb2 | single_bit(u2c, W))
 
     # Rule 3: first degree-2 vertex whose two neighbours are adjacent.
-    nb_all = problem.adj & mask[None, :]  # (n, W)
-    bits = unpack_bits(nb_all, n_total)  # (n, n) neighbour booleans
-    vidx = jnp.arange(n_total, dtype=jnp.int32)
-    first_nb = jnp.where(bits, vidx[None, :], n_total).min(axis=1)
-    last_nb = jnp.where(bits, vidx[None, :], -1).max(axis=1)
-    fc = jnp.clip(first_nb, 0, n_total - 1)
-    lc = jnp.clip(last_nb, 0, n_total - 1)
-    vw_edge = bits[fc, lc]  # adj is symmetric: v's row has bit w
+    nb_all = problem.adj & mask[None, :]  # (n, W); XLA shares it with degrees'
+    vw_edge = _ends_adjacent(problem.adj, nb_all)
     cand3 = inside & (deg == 2) & vw_edge
     u3 = _first_vertex(cand3, n_total)
     has_u3 = u3 < n_total
