@@ -27,7 +27,7 @@ import jax.numpy as jnp
 from repro.api import SolveConfig, SolverSession
 from repro.core import engine as E  # startup-scatter helper for chunked_ab
 from repro.core.superstep import (
-    build_chunk_fn,
+    build_plane_fn,
     build_superstep_fn,
     make_worker_state,
 )
@@ -84,10 +84,10 @@ def chunked_ab(P=64, K=32, R=96, n=32, seed=1):
         step_fn = build_superstep_fn(
             spec, data, num_workers=P, steps_per_round=spr, lanes=1
         )
-        chunk_fn = build_chunk_fn(
-            spec, data, num_workers=P, steps_per_round=spr, lanes=1,
-            chunk_rounds=K,
+        plane = build_plane_fn(
+            spec, steps_per_round=spr, lanes=1, chunk_rounds=K
         )
+        chunk_fn = lambda s: plane(data, s)  # noqa: E731
         # compile
         _, d = step_fn(s0)
         jax.device_get(d)

@@ -128,6 +128,10 @@ def solve_spmd(
 
 
 def _solve_spmd(spec, g, cfg, cache, req, *, initial_state, mesh, injector):
+    if mesh is None and cfg.use_mesh:
+        from repro.launch.mesh import make_solver_mesh
+
+        mesh = make_solver_mesh(cfg.num_workers)
     k = cfg.solo_k()
     W = n_words(g.n)
     cap = cfg.capacity or (4 * g.n + 8 * cfg.lanes)
@@ -185,11 +189,6 @@ def _solve_spmd(spec, g, cfg, cache, req, *, initial_state, mesh, injector):
         state = initial_state
         cap = int(state.frontier.masks.shape[-2])
 
-    if mesh is None and cfg.use_mesh:
-        from repro.launch.mesh import make_solver_mesh
-
-        mesh = make_solver_mesh(cfg.num_workers)
-
     spill = None
     if cfg.frontier_spill:
         if mesh is not None or cfg.use_mesh:
@@ -206,38 +205,15 @@ def _solve_spmd(spec, g, cfg, cache, req, *, initial_state, mesh, injector):
             spill.load_flat(resume_arrays)
 
     use_fpt = cfg.mode == "fpt"
-    if mesh is not None:
-        # mesh planes close over their mesh/sharding: not cacheable (yet)
-        cache.note_bypass()
-        chunk = _engine.build_chunk_fn(
-            spec,
-            data,
-            num_workers=cfg.num_workers,
-            steps_per_round=cfg.steps_per_round,
-            lanes=cfg.lanes,
-            policy_priority=cfg.policy_priority,
-            transfer_pad_words=pad,
-            packed_status=cfg.packed_status,
-            skip_empty_transfer=cfg.skip_empty_transfer,
-            transfer_impl=cfg.transfer_impl,
-            explore_impl=cfg.explore_impl,
-            donate_k=cfg.donate_k,
-            chunk_rounds=cfg.chunk_rounds,
-            fpt_bound=(spec.fpt_target(k) if use_fpt else None),
-            mesh=mesh,
-        )
-        step = lambda s: chunk(s)  # noqa: E731
+    plane = cache.solo_plane(spec, cfg, pad, use_fpt, mesh)
+    cache.note(
+        "solo", spec, cfg, pad, use_fpt, (g.n, W, cap, cfg.num_workers), mesh,
+    )
+    if use_fpt:
+        bound = jnp.int32(spec.fpt_target(k))
+        step = lambda s: plane(data, s, bound)  # noqa: E731
     else:
-        plane = cache.solo_plane(spec, cfg, pad, use_fpt)
-        cache.note(
-            "solo", spec, cfg, pad, use_fpt,
-            (g.n, W, cap, cfg.num_workers),
-        )
-        if use_fpt:
-            bound = jnp.int32(spec.fpt_target(k))
-            step = lambda s: plane(data, s, bound)  # noqa: E731
-        else:
-            step = lambda s: plane(data, s)  # noqa: E731
+        step = lambda s: plane(data, s)  # noqa: E731
 
     t0 = time.perf_counter()
     chunks = 0

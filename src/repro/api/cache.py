@@ -8,10 +8,11 @@ so one jitted function serves every same-shape instance: a serving balancer
 replaying the same (problem, W, B) plane all day compiles once.
 
 :class:`PlaneCache` holds those parametric functions keyed by
-``(kind, problem, config, pad_words, use_fpt)`` and accounts warm/cold at
-SHAPE granularity: a cache *miss* is the first time a shape signature
-``(n, W, capacity[, B])`` hits a plane (jax traces + compiles), a *hit* is
-every subsequent same-shape call (executable reuse, no tracing).  The
+``(kind, problem, config, pad_words, use_fpt, mesh)`` (a solo plane may be
+sharded over a mesh of chips) and accounts warm/cold at SHAPE granularity:
+a cache *miss* is the first time a shape signature ``(n, W, capacity[,
+B])`` hits a plane (jax traces + compiles), a *hit* is every subsequent
+same-shape call (executable reuse, no tracing).  The
 ground-truth compile counter is ``repro.core.superstep.PLANE_TRACES``,
 bumped by a host side effect that only runs while jax traces — tests assert
 hits never trace.
@@ -20,6 +21,7 @@ hits never trace.
 from __future__ import annotations
 
 import dataclasses
+import functools
 
 from repro.core import superstep
 
@@ -31,8 +33,8 @@ class CacheStats:
     ``misses``/``hits`` count shape-level cold/warm calls; ``planes`` is the
     number of distinct parametric functions built; ``shapes`` the distinct
     shape signatures seen; ``bypasses`` counts solves that skipped the cache
-    (currently: mesh-sharded solves, which close over their mesh);
-    ``plane_traces`` snapshots the global jax trace counter.
+    (none do: mesh-sharded planes are cached too); ``plane_traces``
+    snapshots the global jax trace counter.
     """
 
     hits: int = 0
@@ -64,7 +66,9 @@ class PlaneCache:
     # -- plane lookup ----------------------------------------------------------
 
     @staticmethod
-    def _plane_key(kind: str, spec, cfg, pad: int, use_fpt: bool) -> tuple:
+    def _plane_key(
+        kind: str, spec, cfg, pad: int, use_fpt: bool, mesh=None
+    ) -> tuple:
         # key on the knobs the executable actually depends on, so configs
         # differing only in host-side knobs (max_rounds, sim latency, ...)
         # share planes
@@ -73,17 +77,17 @@ class PlaneCache:
             cfg.skip_empty_transfer, cfg.transfer_impl, cfg.explore_impl,
             cfg.donate_k, cfg.chunk_rounds,
         )
-        return (kind, spec, knobs, pad, use_fpt)
+        # a Mesh compares equal by its devices, their shape and axis names
+        return (kind, spec, knobs, pad, use_fpt, mesh)
 
-    def _get(self, kind: str, spec, cfg, pad: int, use_fpt: bool):
-        key = self._plane_key(kind, spec, cfg, pad, use_fpt)
+    def _get(self, kind: str, spec, cfg, pad: int, use_fpt: bool, mesh=None):
+        key = self._plane_key(kind, spec, cfg, pad, use_fpt, mesh)
         plane = self._planes.get(key)
         if plane is None:
-            build = (
-                superstep.build_plane_fn
-                if kind == "solo"
-                else superstep.build_batch_plane_fn
-            )
+            if kind == "solo":
+                build = functools.partial(superstep.build_plane_fn, mesh=mesh)
+            else:
+                build = superstep.build_batch_plane_fn
             plane = build(
                 spec,
                 steps_per_round=cfg.steps_per_round,
@@ -101,9 +105,10 @@ class PlaneCache:
             self._planes[key] = plane
         return plane
 
-    def solo_plane(self, spec, cfg, pad: int, use_fpt: bool):
-        """The parametric ``(data, state[, fpt_bound])`` solo runner."""
-        return self._get("solo", spec, cfg, pad, use_fpt)
+    def solo_plane(self, spec, cfg, pad: int, use_fpt: bool, mesh=None):
+        """The parametric ``(data, state[, fpt_bound])`` solo runner, its
+        workers sharded over ``mesh``'s chips when one is given."""
+        return self._get("solo", spec, cfg, pad, use_fpt, mesh)
 
     def batch_plane(self, spec, cfg, pad: int, use_fpt: bool):
         """The parametric ``(datas, state, done[, fpt_bounds])`` runner."""
@@ -112,11 +117,12 @@ class PlaneCache:
     # -- warm/cold accounting --------------------------------------------------
 
     def note(
-        self, kind: str, spec, cfg, pad: int, use_fpt: bool, shape: tuple
+        self, kind: str, spec, cfg, pad: int, use_fpt: bool, shape: tuple,
+        mesh=None,
     ) -> bool:
         """Record one plane invocation's full signature (plane key + the
         shape tuple jax specializes on); True if it was warm."""
-        key = (self._plane_key(kind, spec, cfg, pad, use_fpt), shape)
+        key = (self._plane_key(kind, spec, cfg, pad, use_fpt, mesh), shape)
         warm = key in self._shapes
         if warm:
             self.hits += 1
@@ -124,9 +130,6 @@ class PlaneCache:
             self.misses += 1
             self._shapes.add(key)
         return warm
-
-    def note_bypass(self) -> None:
-        self.bypasses += 1
 
     def stats(self) -> CacheStats:
         return CacheStats(

@@ -134,6 +134,9 @@ class SolveStats(_DictAccessShim):
     reduce_fires_rule1: int = 0
     reduce_fires_rule2: int = 0
     reduce_fires_rule3: int = 0
+    # -- data plane across chips (spmd) ---------------------------------------
+    # tasks delivered to a worker on another chip of the mesh (0 on one chip)
+    tasks_sent_remote: int = 0
     # -- host sync (spmd) -----------------------------------------------------
     # device-to-host fetches of the host loop while the instance was on the
     # plane (shared with its co-runners on a batched plane), and their bytes
@@ -293,6 +296,7 @@ def from_engine_result(r, *, problem: str, backend: str = "spmd") -> SolveResult
             reduce_fires_rule1=r.reduce_fires_rule1,
             reduce_fires_rule2=r.reduce_fires_rule2,
             reduce_fires_rule3=r.reduce_fires_rule3,
+            tasks_sent_remote=r.tasks_sent_remote,
             host_fetches=r.host_fetches,
             host_fetch_bytes=r.host_fetch_bytes,
         ),

@@ -15,7 +15,7 @@ Responsibilities (the paper's startup/termination bookkeeping):
   traversal, and scatter one task per worker (the paper's seed→waiting-list
   topology); overflow tasks (BFS can over-expand past P) are routed through
   the SAME Algorithm-7 permutation so the equitable topology is preserved;
-* **rounds**: the solve loop is device-resident — ``build_chunk_fn`` runs up
+* **rounds**: the solve loop is device-resident — ``build_plane_fn`` runs up
   to ``chunk_rounds`` supersteps per ``lax.while_loop`` on device, checking
   global quiescence (and, in FPT mode, the bound ``k``) on device; the host
   syncs ONE (done, ran) scalar pair per chunk instead of blocking on a
@@ -45,7 +45,6 @@ import numpy as np
 from repro.core.superstep import (
     REDUCE_COUNTERS,
     WorkerState,
-    build_chunk_fn,
     make_worker_state,
 )
 from repro.core.waiting_list import startup_assignment
@@ -99,6 +98,8 @@ class EngineResult:
     reduce_fires_rule1: int = 0
     reduce_fires_rule2: int = 0
     reduce_fires_rule3: int = 0
+    # tasks delivered to a worker on another chip (0 on one chip)
+    tasks_sent_remote: int = 0
     # device-to-host fetches of the host loop while this instance was on the
     # plane, and their bytes (repro.tracing.Fetches); set by the host drivers
     host_fetches: int = 0
@@ -327,6 +328,7 @@ def _extract_result(
         rounds=rounds,
         nodes_expanded=int(host_state["nodes_expanded"][lane].sum()),
         tasks_transferred=int(host_state["tasks_sent"][lane].sum()),
+        tasks_sent_remote=int(host_state["tasks_sent_remote"][lane].sum()),
         wall_s=wall_s,
         overflow=bool(host_state["overflow"][lane].any()),
         overflow_count=int(host_state["dropped"][lane].sum()),
@@ -345,6 +347,7 @@ def _fetch_batch_state(state: WorkerState) -> dict:
         "best_sol": np.asarray(s.best_sol),
         "nodes_expanded": np.asarray(s.nodes_expanded),
         "tasks_sent": np.asarray(s.tasks_sent),
+        "tasks_sent_remote": np.asarray(s.tasks_sent_remote),
         "overflow": np.asarray(s.frontier.overflow),
         "dropped": np.asarray(s.frontier.dropped),
         "transfer_rounds": np.asarray(s.transfer_rounds),

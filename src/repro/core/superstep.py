@@ -37,13 +37,13 @@ Termination (paper §3.3): transfers cannot straddle a superstep boundary, so
 sent/ack counting and timeout safety mechanisms of the MPI implementation are
 subsumed by the BSP barrier.
 
-The same function runs under ``jax.vmap(axis_name=...)`` (P virtual workers
-on one device — used by tests) and shard_map (one worker per mesh device —
-used by the launcher and the multi-pod dry-run).  ``build_chunk_fn`` wraps
-either path in a device-resident ``lax.while_loop`` that runs up to K
-supersteps per host sync, checking quiescence (and the FPT bound) on device —
-the host only syncs once per chunk, so round latency is hardware-bound, not
-host-dispatch-bound.
+The same function runs for every worker under ``jax.vmap`` (P virtual
+workers on one device) or, on a mesh of chips, under ``shard_map`` over the
+chips with each chip's P / chips workers vmapped inside it.
+``build_plane_fn`` wraps either in a device-resident ``lax.while_loop`` that
+runs up to K supersteps per host sync, checking quiescence (and the FPT
+bound) on device — the host only syncs once per chunk, so round latency is
+hardware-bound, not host-dispatch-bound.
 """
 
 from __future__ import annotations
@@ -92,13 +92,50 @@ TRANSFER_IMPLS = ("sparse", "gather")
 def _shard_map(body, *, mesh, in_specs, out_specs):
     """``jax.shard_map`` with the varying-axes check off: the chunked
     runner's ``lax.while_loop`` carries the worker state sliced from the
-    sharded input (typed as varying over the worker axis), and the
+    sharded input (typed as varying over the chip axis), and the
     pmin/psum that update scalars such as ``best_val`` return values the
     checker types as replicated, so the loop carry would change type.  Kept
     local so :mod:`repro.core` stays launch-independent."""
     return jax.shard_map(
         body, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
         check_vma=False,
+    )
+
+
+# The worker axis is one name under vmap (every worker on one device), or
+# the pair (chips, workers) on a mesh: the mesh's chip axis outside each
+# chip's vmapped block of workers, so that worker w of P sits on chip
+# w // (P / chips).  A collective over the pair runs inside each chip first
+# (a local reduction under vmap), then once across the chips.
+
+
+def _axes(axis_name) -> tuple:
+    return (axis_name,) if isinstance(axis_name, str) else tuple(axis_name)
+
+
+def _all_gather(x, axis_name):
+    """Every worker's ``x``, stacked in worker order."""
+    for name in reversed(_axes(axis_name)):
+        x = jax.lax.all_gather(x, name)
+    if isinstance(axis_name, str):
+        return x
+    return x.reshape((-1,) + x.shape[2:])
+
+
+def _over_workers(collective, x, axis_name):
+    """``collective`` (``psum``, ``pmin``) of ``x`` over every worker."""
+    for name in reversed(_axes(axis_name)):
+        x = collective(x, name)
+    return x
+
+
+def _worker_index(axis_name):
+    if isinstance(axis_name, str):
+        return jax.lax.axis_index(axis_name)
+    chips, workers = axis_name
+    return (
+        jax.lax.axis_index(chips) * jax.lax.axis_size(workers)
+        + jax.lax.axis_index(workers)
     )
 
 
@@ -123,6 +160,8 @@ class WorkerState(NamedTuple):
     reduce_fires_rule1: jnp.ndarray  # () int32
     reduce_fires_rule2: jnp.ndarray  # () int32
     reduce_fires_rule3: jnp.ndarray  # () int32
+    # tasks this worker donated to a worker on another chip (0 on one chip)
+    tasks_sent_remote: jnp.ndarray  # () int32
 
     @property
     def overflow_count(self) -> jnp.ndarray:
@@ -151,11 +190,11 @@ def make_worker_state(capacity: int, W: int, initial_best: int) -> WorkerState:
         reduce_fires_rule1=z,
         reduce_fires_rule2=z,
         reduce_fires_rule3=z,
+        tasks_sent_remote=z,
     )
 
 
-# the reduction's counters by name: results sum them over workers, and a
-# checkpoint may lack them (they are not trajectory)
+# the reduction's counters by name: results sum them over workers
 REDUCE_COUNTERS = (
     "reduce_lane_sweeps",
     "reduce_worker_sweeps",
@@ -163,6 +202,8 @@ REDUCE_COUNTERS = (
     "reduce_fires_rule2",
     "reduce_fires_rule3",
 )
+# counters a checkpoint may lack (they are not trajectory): loaded as 0
+LATE_COUNTERS = REDUCE_COUNTERS + ("tasks_sent_remote",)
 
 
 # -- phase 1: exploration ------------------------------------------------------
@@ -338,7 +379,7 @@ def superstep(
     data: ProblemData,
     state: WorkerState,
     *,
-    axis_name: str,
+    axis_name,
     steps_per_round: int,
     lanes: int,
     policy_priority: bool = True,
@@ -350,6 +391,9 @@ def superstep(
     explore_impl: str = "reference",
 ):
     """One BSP round for a single worker (replicated via vmap/shard_map).
+
+    ``axis_name`` names the worker axis: one vmap axis, or (chips, workers)
+    on a mesh (see :func:`_all_gather`).
 
     ``transfer_pad_words`` emulates the paper's *basic* encoding (§4.3): the
     task record is padded by n·W words of (redundant) adjacency payload so the
@@ -416,22 +460,24 @@ def superstep(
             word = (jnp.clip(pending, 0, 0x7FFF) << 16) | jnp.clip(
                 top_depth, 0, 0xFFFF
             )
-            table_w = jax.lax.all_gather(word, axis_name)  # (P,)
+            table_w = _all_gather(word, axis_name)  # (P,)
             pend_t = table_w >> 16
             depth_t = table_w & 0xFFFF
-            global_best = jax.lax.pmin(
-                jnp.minimum(state.local_best_val, state.best_val), axis_name
+            global_best = _over_workers(
+                jax.lax.pmin,
+                jnp.minimum(state.local_best_val, state.best_val),
+                axis_name,
             )
         else:
             my_status = jnp.stack([pending, top_depth, state.local_best_val])
-            table = jax.lax.all_gather(my_status, axis_name)  # (P, 3)
+            table = _all_gather(my_status, axis_name)  # (P, 3)
             pend_t, depth_t = table[:, 0], table[:, 1]
             global_best = jnp.minimum(table[:, 2].min(), state.best_val)
         state = state._replace(best_val=global_best)
 
         # 3. replicated center matching
         P = pend_t.shape[0]
-        me = jax.lax.axis_index(axis_name).astype(jnp.int32)
+        me = _worker_index(axis_name).astype(jnp.int32)
         send_to, recv_from = match_idle_to_donors(
             pend_t, depth_t, policy_priority, state.rounds
         )
@@ -465,7 +511,7 @@ def superstep(
         if transfer_impl == "gather":
             # reference path: all-gather the full record table (indexed by
             # DONOR), select my donor's block
-            all_records = jax.lax.all_gather(record, axis_name)  # (P, k, REC)
+            all_records = _all_gather(record, axis_name)  # (P, k, REC)
             got = all_records[jnp.clip(my_src, 0, P - 1)]  # (k, REC)
             moved_words = jnp.int32(P * donate_k * rec_words)
         else:
@@ -476,7 +522,7 @@ def superstep(
             buf = jnp.zeros((P, donate_k, rec_words), jnp.uint32)
             tgt = jnp.where(send_to[me] >= 0, send_to[me], jnp.int32(P))
             buf = buf.at[tgt].set(record, mode="drop")
-            delivered = jax.lax.psum(buf, axis_name)  # (P, k, REC)
+            delivered = _over_workers(jax.lax.psum, buf, axis_name)  # (P, k, REC)
             got = delivered[me]  # (k, REC)
             moved_words = n_don.sum() * rec_words
         recv_valid = i_recv & (
@@ -489,13 +535,19 @@ def superstep(
             got[:, 2 * W].astype(jnp.int32),
             recv_valid,
         )
-        return state._replace(
+        state = state._replace(
             frontier=new_frontier,
             tasks_sent=state.tasks_sent + n_don[me],
             tasks_recv=state.tasks_recv + recv_valid.sum().astype(jnp.int32),
             transfer_rounds=state.transfer_rounds + 1,
             payload_words=state.payload_words + moved_words,
         )
+        if isinstance(axis_name, str):  # one chip: nothing leaves it
+            return state
+        # an unmatched donor has send_to -1 and ships nothing (n_don 0)
+        per_chip = P // jax.lax.axis_size(axis_name[0])
+        remote = jnp.where(send_to[me] // per_chip != me // per_chip, n_don[me], 0)
+        return state._replace(tasks_sent_remote=state.tasks_sent_remote + remote)
 
     with jax.named_scope("transfer"):
         if skip_empty_transfer:
@@ -508,7 +560,9 @@ def superstep(
 
     # exact termination: nothing pending anywhere after the transfer phase
     with jax.named_scope("termination"):
-        total_pending = jax.lax.psum(state.frontier.pending(), axis_name)
+        total_pending = _over_workers(
+            jax.lax.psum, state.frontier.pending(), axis_name
+        )
         done = total_pending == 0
     return state, done
 
@@ -527,17 +581,13 @@ def build_superstep_fn(
     transfer_impl: str = "sparse",
     donate_k: int = 1,
     explore_impl: str = "reference",
-    mesh=None,
     axis_name: str = "workers",
 ):
-    """Return a jitted ``state -> (state, done)`` over stacked (P, ...) state.
+    """Return a jitted ``state -> (state, done)`` over stacked (P, ...) state:
+    one superstep of P virtual workers, vmapped on one device.
 
-    mesh=None  -> vmap over the leading axis (P virtual workers, one device).
-    mesh given -> shard_map over the mesh axis ``axis_name`` (one worker per
-                  device; state leading axis must equal mesh size).
-
-    One host sync per superstep — prefer :func:`build_chunk_fn` for solve
-    loops; this remains the single-round entry point for tests/benchmarks.
+    One host sync per superstep — solve loops run :func:`build_plane_fn`;
+    this remains the single-round entry point for tests/benchmarks.
     """
     step = functools.partial(
         superstep,
@@ -554,28 +604,13 @@ def build_superstep_fn(
         donate_k=donate_k,
         explore_impl=explore_impl,
     )
-    if mesh is None:
-        vstep = jax.vmap(step, axis_name=axis_name)
+    vstep = jax.vmap(step, axis_name=axis_name)
 
-        def run(state):
-            state, done = vstep(state)
-            return state, done.all()
+    def run(state):
+        state, done = vstep(state)
+        return state, done.all()
 
-        return jax.jit(run)
-
-    from jax.sharding import PartitionSpec as P
-
-    spec = P(axis_name)
-
-    def body(state_block):
-        # each shard sees a (1, ...) block: strip, step, restore
-        state = jax.tree.map(lambda x: x[0], state_block)
-        state, done = step(state)
-        return jax.tree.map(lambda x: x[None], state), done
-
-    return jax.jit(
-        _shard_map(body, mesh=mesh, in_specs=(spec,), out_specs=(spec, P()))
-    )
+    return jax.jit(run)
 
 
 # -- parametric compiled planes ------------------------------------------------
@@ -614,25 +649,37 @@ def build_plane_fn(
     chunk_rounds: int = 16,
     use_fpt: bool = False,
     axis_name: str = "workers",
+    mesh=None,
 ):
-    """Parametric solo chunk runner (vmap virtual workers).
+    """Parametric solo chunk runner.
 
     Returns a jitted ``(data, state) -> (state, done, ran, hot)`` — or, with
     ``use_fpt``, ``(data, state, fpt_bound) -> ...`` where ``fpt_bound`` is
-    the () int32 INTERNAL decision target.  ``hot`` is the (P,) int32
-    per-worker pending count after the chunk — the spill pump's eviction
-    trigger, computed on device so the host decides whether to pump from
-    scalars it already fetched.  Semantics are otherwise identical to
-    :func:`build_chunk_fn` (mesh=None); the difference is purely that the
-    instance tensors are arguments, so the function is reusable across
-    same-shape instances without re-tracing.
+    the () int32 INTERNAL decision target.  ``state`` is the (P, ...)
+    stacked worker state; ``done`` is exact global quiescence (or the FPT
+    bound reached); ``ran`` the supersteps executed (< ``chunk_rounds`` only
+    when the run finished mid-chunk); ``hot`` the (P,) int32 per-worker
+    pending count after the chunk — the spill pump's eviction trigger,
+    computed on device so the host decides whether to pump from scalars it
+    already fetched.  Up to ``chunk_rounds`` supersteps run inside ONE
+    ``lax.while_loop`` on device, so the host syncs once per chunk.  The
+    instance tensors are arguments, so the function serves every same-shape
+    instance without re-tracing.
+
+    mesh=None: the P workers are virtual, vmapped on one device.  A 1-D
+    ``mesh`` (see :func:`repro.launch.mesh.make_solver_mesh`): the state's
+    worker axis is sharded over the mesh's chips, P / chips workers on each,
+    ``data`` replicated; each chip runs the while_loop over its block of
+    workers, vmapped, and every collective of the superstep spans the chips
+    and the workers together.  Both give bit-identical results.
     """
     if chunk_rounds < 1:
+        # 0 would return (state, done=False, ran=0) forever: the caller's
+        # progress counter never advances and its solve loop cannot exit
         raise ValueError(f"chunk_rounds must be >= 1, got {chunk_rounds}")
     step = functools.partial(
         superstep,
         problem,
-        axis_name=axis_name,
         steps_per_round=steps_per_round,
         lanes=lanes,
         policy_priority=policy_priority,
@@ -648,15 +695,17 @@ def build_plane_fn(
         _, done, i = carry
         return jnp.logical_not(done) & (i < chunk_rounds)
 
-    def _run(data, state, fpt_bound):
-        _count_plane_trace()
-        vstep = jax.vmap(lambda s: step(data, s), axis_name=axis_name)
+    def chunk(data, state, fpt_bound, axes):
+        vstep = jax.vmap(
+            lambda s: step(data, s, axis_name=axes), axis_name=axis_name
+        )
 
         def body(carry):
             state, _, i = carry
             state, done = vstep(state)
             done = done.all()
             if use_fpt:
+                # best_val is the global min after the pmin: replicated
                 done = done | (state.best_val.min() <= fpt_bound)
             return state, done, i + 1
 
@@ -664,6 +713,31 @@ def build_plane_fn(
             cond, body, (state, jnp.bool_(False), jnp.int32(0))
         )
         return state, done, i, pending_per_worker(state.frontier)
+
+    if mesh is None:
+
+        def _run(data, state, fpt_bound):
+            _count_plane_trace()
+            return chunk(data, state, fpt_bound, axis_name)
+
+    else:
+        from jax.sharding import PartitionSpec as P
+
+        (chips,) = mesh.axis_names
+        axes = (chips, axis_name)
+        bound_spec = (P(),) if use_fpt else ()
+        per_chip = _shard_map(
+            lambda data, state, *bound: chunk(
+                data, state, bound[0] if use_fpt else None, axes
+            ),
+            mesh=mesh,
+            in_specs=(P(), P(chips)) + bound_spec,
+            out_specs=(P(chips), P(), P(), P(chips)),
+        )
+
+        def _run(data, state, fpt_bound):
+            _count_plane_trace()
+            return per_chip(data, state, *([fpt_bound] if use_fpt else []))
 
     if use_fpt:
         return jax.jit(_run)
@@ -993,7 +1067,7 @@ def worker_state_from_flat(flat: dict, prefix: str = "worker") -> WorkerState:
         for name in WorkerState._fields
         if name != "frontier" and f"{prefix}.{name}" in flat
     }
-    for name in REDUCE_COUNTERS:  # a checkpoint written before them
+    for name in LATE_COUNTERS:  # a checkpoint written before them
         rest.setdefault(name, jnp.zeros_like(rest["nodes_expanded"]))
     return WorkerState(frontier=frontier, **rest)
 
@@ -1125,111 +1199,3 @@ def build_batch_chunk_fn(
         bounds = jnp.asarray(fpt_bounds, jnp.int32)
         return lambda state, done: plane(datas, state, done, bounds)
     return lambda state, done: plane(datas, state, done)
-
-
-def build_chunk_fn(
-    problem: BranchingProblem,
-    data: ProblemData,
-    *,
-    num_workers: int,
-    steps_per_round: int,
-    lanes: int,
-    policy_priority: bool = True,
-    transfer_pad_words: int = 0,
-    packed_status: bool = True,
-    skip_empty_transfer: bool = True,
-    transfer_impl: str = "sparse",
-    donate_k: int = 1,
-    explore_impl: str = "reference",
-    chunk_rounds: int = 16,
-    fpt_bound: Optional[int] = None,
-    mesh=None,
-    axis_name: str = "workers",
-):
-    """Device-resident multi-round runner: ``state -> (state, done, ran,
-    hot)`` with ``hot`` the (P,) per-worker pending counts after the chunk.
-
-    Runs up to ``chunk_rounds`` supersteps inside ONE ``lax.while_loop`` on
-    device, exiting early on exact global quiescence or (FPT mode) when the
-    global best reaches ``fpt_bound``.  The host syncs once per call instead
-    of once per round — the BSP cadence is set by the hardware, not by host
-    dispatch latency.  ``ran`` is the number of supersteps executed (< K only
-    when the run finished mid-chunk).
-
-    vmap path: the while_loop wraps the vmapped superstep, predicate =
-    all-workers quiescence.  shard_map path: the while_loop runs INSIDE the
-    per-device body — the quiescence flag is already replicated by the psum
-    in the superstep, so every device takes the same branch.
-    """
-    if chunk_rounds < 1:
-        # 0 would return (state, done=False, ran=0) forever: the caller's
-        # progress counter never advances and its solve loop cannot exit
-        raise ValueError(f"chunk_rounds must be >= 1, got {chunk_rounds}")
-    if mesh is None:
-        plane = build_plane_fn(
-            problem,
-            steps_per_round=steps_per_round,
-            lanes=lanes,
-            policy_priority=policy_priority,
-            transfer_pad_words=transfer_pad_words,
-            packed_status=packed_status,
-            skip_empty_transfer=skip_empty_transfer,
-            transfer_impl=transfer_impl,
-            donate_k=donate_k,
-            explore_impl=explore_impl,
-            chunk_rounds=chunk_rounds,
-            use_fpt=(fpt_bound is not None),
-            axis_name=axis_name,
-        )
-        if fpt_bound is not None:
-            bound = jnp.int32(fpt_bound)
-            return lambda state: plane(data, state, bound)
-        return lambda state: plane(data, state)
-
-    step = functools.partial(
-        superstep,
-        problem,
-        data,
-        axis_name=axis_name,
-        steps_per_round=steps_per_round,
-        lanes=lanes,
-        policy_priority=policy_priority,
-        transfer_pad_words=transfer_pad_words,
-        packed_status=packed_status,
-        skip_empty_transfer=skip_empty_transfer,
-        transfer_impl=transfer_impl,
-        donate_k=donate_k,
-        explore_impl=explore_impl,
-    )
-
-    def cond(carry):
-        _, done, i = carry
-        return jnp.logical_not(done) & (i < chunk_rounds)
-
-    from jax.sharding import PartitionSpec as P
-
-    spec = P(axis_name)
-
-    def block(state_block):
-        state0 = jax.tree.map(lambda x: x[0], state_block)
-
-        def body(carry):
-            state, _, i = carry
-            state, done = step(state)
-            if fpt_bound is not None:
-                # best_val is the global min after the pmin phase: replicated
-                done = done | (state.best_val <= fpt_bound)
-            return state, done, i + 1
-
-        state, done, i = jax.lax.while_loop(
-            cond, body, (state0, jnp.bool_(False), jnp.int32(0))
-        )
-        hot = state.frontier.active.sum().astype(jnp.int32)
-        return jax.tree.map(lambda x: x[None], state), done, i, hot[None]
-
-    return jax.jit(
-        _shard_map(
-            block, mesh=mesh, in_specs=(spec,),
-            out_specs=(spec, P(), P(), spec),
-        )
-    )

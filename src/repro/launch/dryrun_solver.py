@@ -3,7 +3,8 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
 
 """Dry-run of the PAPER'S OWN technique at production scale: the SPMD
-superstep engine lowered with one worker per device on a 512-chip mesh.
+superstep engine lowered with one worker per device on a 512-chip mesh
+(the solo plane of :func:`repro.core.superstep.build_plane_fn`, sharded).
 
 Reports the same roofline terms as the LM cells, for the baseline engine
 (3-int status rows, unconditional record all-gather — the straight port of
@@ -11,7 +12,7 @@ the protocol), the optimized control plane (bit-packed 1-int status + pmin
 bound, data plane skipped on match-free rounds) and the sparse data plane
 (masked-psum transfer: payload rows carry only matched records) — §Perf
 cell C of EXPERIMENTS.md.  ``--chunked`` lowers the K-round device-resident
-runner instead of a single superstep (the shape the production launcher
+runner instead of a one-round chunk (the shape the production launcher
 runs: one host sync per chunk).
 
 Usage:
@@ -24,11 +25,7 @@ import json
 import jax
 import jax.numpy as jnp
 
-from repro.core.superstep import (
-    build_chunk_fn,
-    build_superstep_fn,
-    make_worker_state,
-)
+from repro.core.superstep import build_plane_fn, make_worker_state
 from repro.graphs.bitgraph import n_words
 from repro.graphs.generators import erdos_renyi
 from repro.launch.analysis import collective_bytes, roofline
@@ -41,33 +38,30 @@ def lower_engine(n: int, workers: int, *, packed_status, skip_empty_transfer,
                  codec_pad=0, chunked=False, chunk_rounds=16,
                  problem="vertex_cover"):
     mesh = jax.make_mesh(
-        (workers,), ("workers",), axis_types=(jax.sharding.AxisType.Auto,)
+        (workers,), ("chips",), axis_types=(jax.sharding.AxisType.Auto,)
     )
     g = erdos_renyi(n, 4.0 / (n - 1), 0)
     spec = get_problem(problem)
     data = make_data(spec, g)
     W = n_words(n)
     cap = 4 * n + 8 * lanes
-    kwargs = dict(
-        num_workers=workers,
+    fn = build_plane_fn(
+        spec,
         steps_per_round=steps_per_round,
         lanes=lanes,
         transfer_pad_words=codec_pad,
         packed_status=packed_status,
         skip_empty_transfer=skip_empty_transfer,
         transfer_impl=transfer_impl,
+        chunk_rounds=chunk_rounds if chunked else 1,
         mesh=mesh,
     )
-    if chunked:
-        fn = build_chunk_fn(spec, data, chunk_rounds=chunk_rounds, **kwargs)
-    else:
-        fn = build_superstep_fn(spec, data, **kwargs)
     state = jax.eval_shape(
         lambda: jax.vmap(lambda _: make_worker_state(cap, W, n + 1))(
             jnp.arange(workers)
         )
     )
-    lowered = fn.lower(state)
+    lowered = fn.lower(data, state)
     compiled = lowered.compile()
     cost = compiled.cost_analysis()
     coll = collective_bytes(compiled.as_text())

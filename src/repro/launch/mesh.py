@@ -19,9 +19,17 @@ def make_production_mesh(*, multi_pod: bool = False):
 
 
 def make_solver_mesh(num_workers: int | None = None):
-    """1-D mesh for the branching engine: one worker per device."""
-    n = num_workers or len(jax.devices())
-    return jax.make_mesh((n,), ("workers",), axis_types=(AxisType.Auto,))
+    """1-D mesh over every device JAX sees, axis ``chips``, for the
+    branching engine: each device runs ``num_workers / devices`` virtual
+    workers.  Raises ``ValueError`` when the device count does not divide
+    ``num_workers``."""
+    n = len(jax.devices())
+    if num_workers is not None and (num_workers < n or num_workers % n):
+        raise ValueError(
+            f"num_workers={num_workers} cannot be split evenly over the "
+            f"{n} devices of the mesh: use a multiple of {n}"
+        )
+    return jax.make_mesh((n,), ("chips",), axis_types=(AxisType.Auto,))
 
 
 def batch_axes_for(global_batch: int, mesh) -> tuple | None:

@@ -4,7 +4,8 @@ one config.
   --problem NAME     which branching problem (vertex_cover, max_clique, mis;
                      see repro.problems.registry)
   --engine spmd         the TPU-adapted superstep engine (vmap of P virtual
-                        workers on CPU; one worker per device with --use-mesh)
+                        workers on one device; with --use-mesh, P / devices
+                        of them on each device JAX sees)
   --engine protocol_sim the faithful asynchronous MPI-protocol simulator
                         (alias: protocol)
   --engine centralized  the fully-centralized baseline (Abu-Khzam 2006;

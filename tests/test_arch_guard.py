@@ -258,6 +258,7 @@ SOLVE_STATS_FIELDS = [
     "service",
     "solutions",
     "spilled_tasks",
+    "tasks_sent_remote",
     "termination_cancelled",
     "ticks",
     "total_bytes",

@@ -1,5 +1,6 @@
-"""The mesh path (one worker per device under ``shard_map``) solves
-bit-identically to the vmap path (the same workers, virtual, on one device).
+"""The mesh path (the workers sharded over the devices under ``shard_map``)
+solves bit-identically to the vmap path (the same workers, virtual, on one
+device), and takes its plane from the session's cache as the vmap path does.
 
 The mesh needs several devices, and the CPU backend's device count is fixed
 when JAX starts, so each case runs in a child process with four virtual CPU
@@ -43,6 +44,7 @@ for use_mesh in (False, True):
     out["bypasses_" + ("mesh" if use_mesh else "vmap")] = (
         session.cache_stats()["bypasses"]
     )
+    out["remote_" + ("mesh" if use_mesh else "vmap")] = r.stats.tasks_sent_remote
 print(json.dumps(out))
 """
 
@@ -67,6 +69,9 @@ def test_mesh_solve_is_bit_identical_to_vmap(problem, n, p, lanes):
     )
     assert proc.returncode == 0, proc.stderr[-4000:]
     out = json.loads(proc.stdout.splitlines()[-1])
-    # the mesh solve really took the shard_map path (it bypasses the cache)
-    assert out["bypasses_vmap"] == 0 and out["bypasses_mesh"] == 1
+    # both solves took their plane from the cache; the mesh solve really
+    # ran sharded (one worker per device: every task it moved crossed chips)
+    assert out["bypasses_vmap"] == 0 and out["bypasses_mesh"] == 0
+    assert out["remote_vmap"] == 0
+    assert out["remote_mesh"] == out["mesh"]["tasks_transferred"]
     assert out["mesh"] == out["vmap"]

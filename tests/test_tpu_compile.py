@@ -15,7 +15,7 @@ import pytest
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import SingleDeviceSharding
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec, SingleDeviceSharding
 
 from repro.core import superstep
 from repro.graphs.generators import erdos_renyi
@@ -27,17 +27,21 @@ T = 64  # tasks per kernel call: eight sublane tiles of task rows
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topo():
     from jax.experimental import topologies
 
     with pytest.MonkeyPatch.context() as mp:
         mp.setenv("TPU_LOG_DIR", "disabled")  # else libtpu logs under /tmp
         try:
-            topo = topologies.get_topology_desc(
+            return topologies.get_topology_desc(
                 platform="tpu", topology_name="v5e:2x2"
             )
         except Exception as e:  # noqa: BLE001 - any failure means "cannot"
             pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
@@ -89,3 +93,36 @@ def test_fused_solo_plane_compiles_with_the_kernel(one_chip, monkeypatch):
         .as_text()
     )
     assert "tpu_custom_call" in text
+
+
+def test_mesh_plane_compiles_for_four_chips(topo, monkeypatch):
+    """The solo plane sharded over a described four-chip host, two workers
+    of 8 lanes on each chip: it compiles with the kernel, and the center's
+    collectives run across the chips."""
+    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "0")  # as on a TPU runtime
+    n, workers, lanes = 256, 8, 8
+    mesh = Mesh(np.array(topo.devices), ("chips",), axis_types=(AxisType.Auto,))
+    spec = get_problem("vertex_cover")
+    g = erdos_renyi(n, 0.05, 0)
+    plane = superstep.build_plane_fn(
+        spec, steps_per_round=32, lanes=lanes, explore_impl="fused", mesh=mesh
+    )
+    cap = 4 * n + 8 * lanes
+    state = jax.eval_shape(
+        lambda: jax.vmap(
+            lambda _: superstep.make_worker_state(cap, g.W, n + 1)
+        )(jnp.arange(workers))
+    )
+    described = lambda tree, parts: jax.tree.map(  # noqa: E731
+        lambda x: _shape(np.shape(x), x.dtype, NamedSharding(mesh, parts)), tree
+    )
+    text = (
+        plane.lower(
+            described(make_data(spec, g), PartitionSpec()),
+            described(state, PartitionSpec("chips")),
+        )
+        .compile()
+        .as_text()
+    )
+    assert "tpu_custom_call" in text
+    assert "all-reduce(" in text and "replica_groups={{0,1,2,3}}" in text

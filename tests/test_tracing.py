@@ -187,11 +187,11 @@ def test_checkpoint_round_trips_the_counters_and_loads_old_ones_as_zero():
     assert int(state.reduce_lane_sweeps.sum()) > 0
     flat = superstep.worker_state_to_flat(state)
     back = superstep.worker_state_from_flat(flat)
-    for name in REDUCE:
+    for name in superstep.LATE_COUNTERS:
         assert (np.asarray(getattr(back, name)) == np.asarray(getattr(state, name))).all()
-    old = {k: v for k, v in flat.items() if k.split(".", 1)[1] not in REDUCE}
+    old = {k: v for k, v in flat.items() if k.split(".", 1)[1] not in superstep.LATE_COUNTERS}
     loaded = superstep.worker_state_from_flat(old)
-    for name in REDUCE:
+    for name in superstep.LATE_COUNTERS:
         leaf = np.asarray(getattr(loaded, name))
         assert leaf.shape == np.asarray(state.nodes_expanded).shape and not leaf.any()
     assert (np.asarray(loaded.nodes_expanded) == np.asarray(state.nodes_expanded)).all()
