@@ -128,12 +128,15 @@ class SolveStats(_DictAccessShim):
     # -- explore's reduction (spmd; 0 without one) ----------------------------
     # summed over workers: sweeps run by expanded lanes (each lane's last
     # sweep changes nothing), the lockstep loop's trips (per explore step,
-    # the most sweeps of any lane of the worker), and each rule's firings
+    # the most sweeps of any lane of the worker), each rule's firings, and
+    # the degree-2 candidates rule 3 examined (in each sweep where rules 1
+    # and 2 do not fire: up to and including its vertex, else all)
     reduce_lane_sweeps: int = 0
     reduce_worker_sweeps: int = 0
     reduce_fires_rule1: int = 0
     reduce_fires_rule2: int = 0
     reduce_fires_rule3: int = 0
+    reduce_rule3_probes: int = 0
     # -- data plane across chips (spmd) ---------------------------------------
     # tasks delivered to a worker on another chip of the mesh (0 on one chip)
     tasks_sent_remote: int = 0
@@ -296,6 +299,7 @@ def from_engine_result(r, *, problem: str, backend: str = "spmd") -> SolveResult
             reduce_fires_rule1=r.reduce_fires_rule1,
             reduce_fires_rule2=r.reduce_fires_rule2,
             reduce_fires_rule3=r.reduce_fires_rule3,
+            reduce_rule3_probes=r.reduce_rule3_probes,
             tasks_sent_remote=r.tasks_sent_remote,
             host_fetches=r.host_fetches,
             host_fetch_bytes=r.host_fetch_bytes,

@@ -98,6 +98,7 @@ class EngineResult:
     reduce_fires_rule1: int = 0
     reduce_fires_rule2: int = 0
     reduce_fires_rule3: int = 0
+    reduce_rule3_probes: int = 0
     # tasks delivered to a worker on another chip (0 on one chip)
     tasks_sent_remote: int = 0
     # device-to-host fetches of the host loop while this instance was on the

@@ -160,6 +160,7 @@ class WorkerState(NamedTuple):
     reduce_fires_rule1: jnp.ndarray  # () int32
     reduce_fires_rule2: jnp.ndarray  # () int32
     reduce_fires_rule3: jnp.ndarray  # () int32
+    reduce_rule3_probes: jnp.ndarray  # () int32 -- candidates rule 3 examined
     # tasks this worker donated to a worker on another chip (0 on one chip)
     tasks_sent_remote: jnp.ndarray  # () int32
 
@@ -190,6 +191,7 @@ def make_worker_state(capacity: int, W: int, initial_best: int) -> WorkerState:
         reduce_fires_rule1=z,
         reduce_fires_rule2=z,
         reduce_fires_rule3=z,
+        reduce_rule3_probes=z,
         tasks_sent_remote=z,
     )
 
@@ -201,6 +203,7 @@ REDUCE_COUNTERS = (
     "reduce_fires_rule1",
     "reduce_fires_rule2",
     "reduce_fires_rule3",
+    "reduce_rule3_probes",
 )
 # counters a checkpoint may lack (they are not trajectory): loaded as 0
 LATE_COUNTERS = REDUCE_COUNTERS + ("tasks_sent_remote",)
@@ -289,6 +292,7 @@ def _explore_one_round(
     # slowest lane, popped or not
     sweeps = jnp.where(valid, ex.work.sweeps, 0).sum().astype(jnp.int32)
     fires = jnp.where(valid[:, None], ex.work.fires, 0).sum(axis=0).astype(jnp.int32)
+    probes = jnp.where(valid, ex.work.rule3_probes, 0).sum().astype(jnp.int32)
     return state._replace(
         reduce_lane_sweeps=state.reduce_lane_sweeps + sweeps,
         reduce_worker_sweeps=state.reduce_worker_sweeps
@@ -296,6 +300,7 @@ def _explore_one_round(
         reduce_fires_rule1=state.reduce_fires_rule1 + fires[0],
         reduce_fires_rule2=state.reduce_fires_rule2 + fires[1],
         reduce_fires_rule3=state.reduce_fires_rule3 + fires[2],
+        reduce_rule3_probes=state.reduce_rule3_probes + probes,
     )
 
 

@@ -103,11 +103,13 @@ class ExpandResult(NamedTuple):
 
 class ReduceWork(NamedTuple):
     """What a reduction to fixpoint did on each lane of a batch: the sweeps
-    its loop ran (the last one changes nothing) and how often each of up to
-    three rules fired.  Read by the engine's ``reduce_*`` counters."""
+    its loop ran (the last one changes nothing), how often each of up to
+    three rules fired, and the candidates its third rule examined.  Read
+    by the engine's ``reduce_*`` counters."""
 
     sweeps: jnp.ndarray  # (L,) int32
     fires: jnp.ndarray  # (L, 3) int32 -- firings of rules 1, 2, 3
+    rule3_probes: jnp.ndarray  # (L,) int32
 
 
 # -- packed-bitset primitives (problem-agnostic device ops) --------------------

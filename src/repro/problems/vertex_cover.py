@@ -75,10 +75,12 @@ def _first_vertex(cond: jnp.ndarray, n_total: int) -> jnp.ndarray:
     return idx.min()
 
 
-def _first_neighbour(rows: jnp.ndarray) -> jnp.ndarray:
-    """Lowest set bit of each packed (n, W) row, word by word: (n,) int32,
-    n for an empty row."""
-    n_total, W = rows.shape
+def _first_neighbour(rows: jnp.ndarray, n_total: int | None = None) -> jnp.ndarray:
+    """Lowest set bit of each packed (rows, W) row, word by word: (rows,)
+    int32, ``n_total`` (the graph's vertices; by default one row each) for
+    an empty row."""
+    n_total = rows.shape[0] if n_total is None else n_total
+    W = rows.shape[1]
     low = rows & (~rows + jnp.uint32(1))  # each word's lowest set bit alone
     bit = WORD_BITS - 1 - jax.lax.clz(low).astype(jnp.int32)
     first = jnp.arange(W, dtype=jnp.int32) * WORD_BITS + bit
@@ -87,80 +89,134 @@ def _first_neighbour(rows: jnp.ndarray) -> jnp.ndarray:
 
 def _ends_adjacent(adj: jnp.ndarray, rows: jnp.ndarray) -> jnp.ndarray:
     """Whether the two neighbours of each row of ``rows`` (packed neighbour
-    rows inside the mask) are adjacent, (n,) bool; meaningful for rows of
+    rows inside the mask) are adjacent, (rows,) bool; meaningful for rows of
     exactly two neighbours.  The first neighbour's row of the shared ``adj``
     meets such a row in the other neighbour's bit alone, if at all (graphs
-    have no self-loops).  A row lookup: O(n W) words, no n x n table."""
-    fc = jnp.clip(_first_neighbour(rows), 0, adj.shape[0] - 1)
+    have no self-loops).  One row lookup a row: no n x n table."""
+    fc = jnp.clip(_first_neighbour(rows, adj.shape[0]), 0, adj.shape[0] - 1)
     return (adj[fc] & rows).any(axis=-1)
 
 
-def _reduce_step(problem: ProblemData, mask, sol_mask):
-    """One reduction sweep.  Returns (mask, sol_mask, rule): the rule that
-    fired (1, 2 or 3; () int32), 0 when none did."""
+# Rule 3 tests its degree-2 candidates this many at a time, lowest first.
+RULE3_CHUNK = 16
+
+
+def _ranks(bits: jnp.ndarray, W: int):
+    """Each vertex's rank among the set bits of an (n,) bool vector (how many
+    set bits lie below it), (n,) int32, and their count; from packed words,
+    with no n-long prefix sum."""
+    words = pack_bits(bits, W)
+    per_word = jax.lax.population_count(words).astype(jnp.int32)  # (W,)
+    w = jnp.arange(W)
+    before = jnp.where(w[None, :] < w[:, None], per_word[None, :], 0).sum(axis=1)
+    below = (jnp.uint32(1) << jnp.arange(WORD_BITS, dtype=jnp.uint32)) - jnp.uint32(1)
+    within = jax.lax.population_count(words[:, None] & below[None, :]).astype(jnp.int32)
+    rank = (before[:, None] + within).reshape(-1)[: bits.shape[-1]]
+    return rank, per_word.sum()
+
+
+def _probe(problem: ProblemData, mask, cand, rank, start):
+    """The lowest of the candidates ranked ``start`` to ``start + K - 1``
+    whose two neighbours are adjacent (() int32, n if none), and the next
+    chunk's first rank: 2 K row lookups."""
+    n_total = problem.adj.shape[0]
+    K = min(RULE3_CHUNK, n_total)
+    v = jnp.arange(n_total, dtype=jnp.int32)
+    want = start + jnp.arange(K, dtype=jnp.int32)
+    u = jnp.where(cand[None, :] & (rank[None, :] == want[:, None]), v[None, :], n_total).min(axis=1)
+    rows = problem.adj[jnp.minimum(u, n_total - 1)] & mask  # (K, W)
+    hit = (u < n_total) & _ends_adjacent(problem.adj, rows)
+    return jnp.where(hit, u, n_total).min(), start + K
+
+
+def _sweep(problem: ProblemData, mask, sol_mask, active):
+    """One reduction sweep.  Returns (mask, sol_mask, rule, probes): the rule
+    that fired (1, 2 or 3; () int32), 0 when none did, and the degree-2
+    candidates rule 3 examined, in index order up to and including its
+    vertex (all of them if none qualifies; 0 where rule 1 or 2 fires or the
+    lane is not ``active``).  The ``sweep`` scope holds the code that runs
+    once a sweep; rule 3's further chunks run in a loop of their own."""
     n_total, W = problem.adj.shape
-    deg = degrees(problem, mask)
-    inside = deg >= 0
+    with jax.named_scope("sweep"):
+        deg = degrees(problem, mask)
+        inside = deg >= 0
 
-    # Rule 1: drop all isolated vertices at once (removals never conflict).
-    iso = inside & (deg == 0)
-    any_iso = iso.any()
-    mask_r1 = mask & ~pack_bits(iso, W)
+        # Rule 1: drop all isolated vertices at once (removals never conflict).
+        iso = inside & (deg == 0)
+        any_iso = iso.any()
+        mask_r1 = mask & ~pack_bits(iso, W)
 
-    # Rule 2: one degree-1 vertex per sweep (batching could over-add on
-    # isolated edges where both endpoints have degree 1).
-    u2 = _first_vertex(inside & (deg == 1), n_total)
-    has_u2 = u2 < n_total
-    u2c = jnp.minimum(u2, n_total - 1)
-    nb2 = problem.adj[u2c] & mask
-    sol_r2 = sol_mask | nb2
-    mask_r2 = mask & ~(nb2 | single_bit(u2c, W))
+        # Rule 2: one degree-1 vertex per sweep (batching could over-add on
+        # isolated edges where both endpoints have degree 1).
+        u2 = _first_vertex(inside & (deg == 1), n_total)
+        has_u2 = u2 < n_total
+        u2c = jnp.minimum(u2, n_total - 1)
+        nb2 = problem.adj[u2c] & mask
+        sol_r2 = sol_mask | nb2
+        mask_r2 = mask & ~(nb2 | single_bit(u2c, W))
 
-    # Rule 3: first degree-2 vertex whose two neighbours are adjacent.
-    nb_all = problem.adj & mask[None, :]  # (n, W); XLA shares it with degrees'
-    vw_edge = _ends_adjacent(problem.adj, nb_all)
-    cand3 = inside & (deg == 2) & vw_edge
-    u3 = _first_vertex(cand3, n_total)
-    has_u3 = u3 < n_total
-    u3c = jnp.minimum(u3, n_total - 1)
-    nb3 = problem.adj[u3c] & mask
-    sol_r3 = sol_mask | nb3
-    mask_r3 = mask & ~(nb3 | single_bit(u3c, W))
+        # Rule 3: first degree-2 vertex whose two neighbours are adjacent.
+        # Only a lane where rules 1 and 2 do not fire offers candidates (a
+        # lane done with its loop none, or it would hold the lockstep),
+        # probed a chunk at a time until every lane has its vertex or runs
+        # out.
+        cand = inside & (deg == 2) & ~any_iso & ~has_u2 & active
+        rank, count = _ranks(cand, W)
+        first = _probe(problem, mask, cand, rank, jnp.int32(0))
+    with jax.named_scope("rule3_chunks"):
+        u3, _ = jax.lax.while_loop(
+            lambda c: (c[0] >= n_total) & (c[1] < count),
+            lambda c: _probe(problem, mask, cand, rank, c[1]),
+            first,
+        )
+    with jax.named_scope("sweep"):
+        has_u3 = u3 < n_total
+        u3c = jnp.minimum(u3, n_total - 1)
+        probes = jnp.where(has_u3, rank[u3c] + 1, count)
+        nb3 = problem.adj[u3c] & mask
+        sol_r3 = sol_mask | nb3
+        mask_r3 = mask & ~(nb3 | single_bit(u3c, W))
 
-    # Priority: rule 1 > rule 2 > rule 3 (mirrors the host reference).
-    new_mask = jnp.where(any_iso, mask_r1, jnp.where(has_u2, mask_r2, jnp.where(has_u3, mask_r3, mask)))
-    new_sol = jnp.where(any_iso, sol_mask, jnp.where(has_u2, sol_r2, jnp.where(has_u3, sol_r3, sol_mask)))
-    rule = jnp.where(any_iso, 1, jnp.where(has_u2, 2, jnp.where(has_u3, 3, 0)))
-    return new_mask, new_sol, rule.astype(jnp.int32)
+        # Priority: rule 1 > rule 2 > rule 3 (mirrors the host reference).
+        new_mask = jnp.where(any_iso, mask_r1, jnp.where(has_u2, mask_r2, jnp.where(has_u3, mask_r3, mask)))
+        new_sol = jnp.where(any_iso, sol_mask, jnp.where(has_u2, sol_r2, jnp.where(has_u3, sol_r3, sol_mask)))
+        rule = jnp.where(any_iso, 1, jnp.where(has_u2, 2, jnp.where(has_u3, 3, 0)))
+    return new_mask, new_sol, rule.astype(jnp.int32), probes
+
+
+def _reduce_step(problem: ProblemData, mask, sol_mask):
+    """One reduction sweep as :func:`sequential.reduce_sweep` gives it:
+    (mask, sol_mask, rule)."""
+    return _sweep(problem, mask, sol_mask, True)[:3]
 
 
 def _reduce_counted(problem: ProblemData, mask, sol_mask):
     """Apply rules 1-3 to fixpoint (bounded while_loop); returns (mask,
     sol_mask, :class:`ReduceWork`): the sweeps the loop ran, its last one
-    (which changes nothing) included, and the (3,) firings of each rule.
+    (which changes nothing) included, the (3,) firings of each rule and
+    rule 3's probes.
     Every firing removes a vertex, so the bound of n + 1 sweeps never cuts
     the loop short and sweeps == fires.sum() + 1."""
 
     def cond(state):
-        _, _, changed, it, _ = state
+        _, _, changed, it, _, _ = state
         return changed & (it < problem.adj.shape[0] + 1)
 
     def body(state):
-        m, s, _, it, fires = state
-        with jax.named_scope("sweep"):
-            m2, s2, rule = _reduce_step(problem, m, s)
-            fires = fires + (jnp.arange(1, 4) == rule).astype(jnp.int32)
-        return (m2, s2, rule > 0, it + 1, fires)
+        m, s, changed, it, fires, probes = state
+        m2, s2, rule, p = _sweep(problem, m, s, changed)
+        fires = fires + (jnp.arange(1, 4) == rule).astype(jnp.int32)
+        return (m2, s2, rule > 0, it + 1, fires, probes + p)
 
     # initial `changed` is derived from mask (always True) so its varying-
     # manual-axes match the body output under shard_map (see JAX scan-vma).
     changed0 = popcount(mask) >= 0
     with jax.named_scope("reduce"):
-        mask, sol_mask, _, sweeps, fires = jax.lax.while_loop(
+        mask, sol_mask, _, sweeps, fires, probes = jax.lax.while_loop(
             cond, body,
-            (mask, sol_mask, changed0, jnp.int32(0), jnp.zeros(3, jnp.int32)),
+            (mask, sol_mask, changed0, jnp.int32(0), jnp.zeros(3, jnp.int32), jnp.int32(0)),
         )
-    return mask, sol_mask, ReduceWork(sweeps=sweeps, fires=fires)
+    return mask, sol_mask, ReduceWork(sweeps=sweeps, fires=fires, rule3_probes=probes)
 
 
 def reduce_instance(problem: ProblemData, mask, sol_mask):

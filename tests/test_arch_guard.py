@@ -253,6 +253,7 @@ SOLVE_STATS_FIELDS = [
     "reduce_fires_rule2",
     "reduce_fires_rule3",
     "reduce_lane_sweeps",
+    "reduce_rule3_probes",
     "reduce_worker_sweeps",
     "resumed_from",
     "service",

@@ -153,6 +153,7 @@ def _hand_built_batch(masks_spec, P=4, cap=8, W=1, n=16):
         reduce_fires_rule1=z,
         reduce_fires_rule2=z,
         reduce_fires_rule3=z,
+        reduce_rule3_probes=z,
         tasks_sent_remote=z,
     )
     v = np.arange(n, dtype=np.int32)

@@ -185,6 +185,7 @@ def test_checkpoint_round_trips_the_counters_and_loads_old_ones_as_zero():
     state = engine._scatter_startup(state, spec, g, 2)
     state = plane(base.make_data(spec, g), state)[0]
     assert int(state.reduce_lane_sweeps.sum()) > 0
+    assert int(state.reduce_rule3_probes.sum()) > 0
     flat = superstep.worker_state_to_flat(state)
     back = superstep.worker_state_from_flat(flat)
     for name in superstep.LATE_COUNTERS:
