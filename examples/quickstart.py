@@ -1,4 +1,4 @@
-"""Quickstart: the paper's solver + the LM substrate in two minutes on CPU.
+"""Quickstart: the paper's solver on one graph, three engines, on CPU.
 
   PYTHONPATH=src python examples/quickstart.py
 """
@@ -7,18 +7,14 @@ import sys
 
 sys.path.insert(0, "src")
 
-import jax
-
 from repro.api import SolveConfig, SolverSession
 from repro.graphs.generators import erdos_renyi
-from repro.launch.train import train_loop
-from repro.configs.registry import get_smoke_config
 from repro.problems.sequential import verify_cover
 
 
 def main():
-    # --- 1. the paper's workload: minimum vertex cover, three engines -----
-    # one façade over every engine: pick a backend, get one result schema
+    # the paper's workload, minimum vertex cover, on three engines: one
+    # façade over every engine, pick a backend, get one result schema
     g = erdos_renyi(50, 4 / 49, seed=7)
     print(f"graph: n={g.n} m={g.num_edges}")
     cfg = SolveConfig(num_workers=6, steps_per_round=16)
@@ -41,13 +37,6 @@ def main():
         f"({r.rounds} supersteps, {r.tasks_transferred} transfers, "
         f"verified={ok})"
     )
-
-    # --- 2. the LM substrate: a tiny qwen-style model learns --------------
-    cfg = get_smoke_config("qwen1_5_0_5b")
-    print(f"\ntraining {cfg.name} (d={cfg.d_model}, L={cfg.n_layers}) ...")
-    _, _, losses = train_loop(cfg, steps=60, batch=8, seq=64, log_every=20)
-    first, last = sum(losses[:6]) / 6, sum(losses[-6:]) / 6
-    print(f"loss {first:.3f} -> {last:.3f} ({'OK' if last < first else 'FLAT'})")
 
 
 if __name__ == "__main__":

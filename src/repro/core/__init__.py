@@ -1,3 +1,4 @@
-# The paper's primary contribution — implement the SYSTEM here
-# (scheduler, optimizer, data path, serving loop, etc.) in the
-# host framework. Add sibling subpackages for substrates.
+"""The paper's system: the semi-centralized scheduler (``center``), the
+SPMD superstep engine and its host driver, task frontier and spill tiers,
+task codecs, and the asynchronous protocol simulation it is checked against.
+"""

@@ -1,10 +1,8 @@
-"""Pallas TPU kernels for the compute hot spots (+ jnp oracles).
+"""Pallas TPU kernels for the solver's hot spots (+ jnp oracles).
 
-* ``bitset_ops``       — batched induced-subgraph degrees (B&B branching);
-* ``flash_attention``  — blockwise online-softmax attention (LM layers);
-* ``wkv6``             — chunked data-dependent-decay recurrence (RWKV6).
+* ``bitset_ops`` — batched induced-subgraph degrees (B&B branching).
 
-Each subpackage ships ``kernel.py`` (pl.pallas_call + BlockSpec),
+The subpackage ships ``kernel.py`` (pl.pallas_call + BlockSpec),
 ``ops.py`` (jit'd dispatch wrapper) and ``ref.py`` (pure-jnp oracle);
 kernels are validated with interpret=True on CPU and target TPU natively.
 """

@@ -100,16 +100,3 @@ def roofline(flops: float, bytes_accessed: float, coll_bytes: float) -> dict:
         "compute_fraction": compute_s / bound_s if bound_s else 0.0,
     }
 
-
-def model_flops(cfg, shape) -> float:
-    """6·N·D (dense) / 6·N_active·D (MoE) useful-FLOPs estimate; forward-only
-    kinds use 2·N·D.  D = tokens processed in the step."""
-    n_active = cfg.active_params_count()
-    if shape.kind == "train":
-        tokens = shape.global_batch * shape.seq_len
-        return 6.0 * n_active * tokens
-    if shape.kind == "prefill":
-        tokens = shape.global_batch * shape.seq_len
-        return 2.0 * n_active * tokens
-    # decode: one token per sequence
-    return 2.0 * n_active * shape.global_batch

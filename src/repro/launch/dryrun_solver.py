@@ -6,7 +6,7 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
 superstep engine lowered with one worker per device on a 512-chip mesh
 (the solo plane of :func:`repro.core.superstep.build_plane_fn`, sharded).
 
-Reports the same roofline terms as the LM cells, for the baseline engine
+Reports the roofline terms of :mod:`repro.launch.analysis` for the baseline engine
 (3-int status rows, unconditional record all-gather — the straight port of
 the protocol), the optimized control plane (bit-packed 1-int status + pmin
 bound, data plane skipped on match-free rounds) and the sparse data plane

@@ -13,8 +13,6 @@ Usage:
   PYTHONPATH=src python -m repro.launch.serve --smoke
   PYTHONPATH=src python -m repro.launch.serve --problem max_clique \
       --requests 32 --lanes 8 --rate 4.0 --n 24
-
-(The old batched LM-decode demo lives in ``examples/serve_lm.py``.)
 """
 
 from __future__ import annotations

@@ -2,7 +2,6 @@
 
 import os
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -13,8 +12,6 @@ from repro.checkpoint.store import (
     save_checkpoint,
     wait_for_pending,
 )
-from repro.configs.registry import get_smoke_config
-from repro.launch.train import train_loop
 
 
 def test_roundtrip(tmp_path):
@@ -140,21 +137,3 @@ def test_fingerprint_mismatch_refuses_resume(tmp_path):
     r = SolverSession.resume(d, max_rounds=10_000)
     assert r.found
 
-
-def test_resume_reproduces_loss_curve(tmp_path):
-    """Train 12 steps straight vs 6 + crash + resume 6: identical losses —
-    the deterministic pipeline + checkpoint contract."""
-    cfg = get_smoke_config("qwen1_5_0_5b")
-    ck = str(tmp_path / "ck")
-    _, _, full = train_loop(cfg, steps=12, batch=4, seq=32, ckpt_dir=None, seed=3)
-    _, _, first = train_loop(
-        cfg, steps=6, batch=4, seq=32, ckpt_dir=ck, ckpt_every=3, seed=3
-    )
-    wait_for_pending()
-    _, _, second = train_loop(
-        cfg, steps=12, batch=4, seq=32, ckpt_dir=ck, ckpt_every=100,
-        resume=True, seed=3,
-    )
-    resumed = first + second
-    assert len(resumed) == len(full)
-    np.testing.assert_allclose(resumed, full, rtol=2e-4, atol=2e-4)

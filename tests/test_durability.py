@@ -27,9 +27,19 @@ from repro.api import (
 )
 from repro.core import superstep
 from repro.graphs.generators import erdos_renyi
+from repro.problems.registry import get_problem
 
 # checkpoint at EVERY host-sync boundary: one round per chunk, tiny rounds
 CFG = dict(num_workers=4, steps_per_round=2, chunk_rounds=1, checkpoint_every=1)
+
+PROBLEMS = ["vertex_cover", "max_clique", "mis"]
+
+
+def _edge_p(problem, p):
+    """The edge density for ``problem`` where vertex cover uses ``p``: MIS
+    searches sparse graphs for hundreds of rounds, so its instances are
+    denser, which keeps every resume loop a few dozen chunks long."""
+    return 0.6 if problem == "mis" else p
 
 
 def _assert_same(a, b):
@@ -54,20 +64,31 @@ def _steps(d):
     )
 
 
-@pytest.mark.parametrize(
-    "mode_kw",
-    [dict(), dict(mode="fpt", k=20)],
-    ids=["bnb", "fpt"],
-)
-def test_solo_resume_bit_identical_at_every_boundary(tmp_path, mode_kw):
-    g = erdos_renyi(34, 0.25, seed=3)
+def _fpt_miss_k(problem, g):
+    """An fpt target the graph misses, so the whole tree is searched:
+    vertex cover asks for 20 under its optimum of 23, max clique and MIS
+    for one past their optimum."""
+    if problem == "vertex_cover":
+        return 20
+    return get_problem(problem).sequential(g)[0] + 1
+
+
+@pytest.mark.parametrize("problem", PROBLEMS)
+@pytest.mark.parametrize("mode", ["bnb", "fpt"])
+def test_solo_resume_bit_identical_at_every_boundary(tmp_path, mode, problem):
+    g = erdos_renyi(34, _edge_p(problem, 0.25), seed=3)
+    mode_kw = dict(mode="fpt", k=_fpt_miss_k(problem, g)) if mode == "fpt" else {}
     cfg = SolveConfig(**CFG, **mode_kw)
     cache = PlaneCache()
-    base = SolverSession(config=cfg, cache=cache).solve(g)
+    base = SolverSession(problem, config=cfg, cache=cache).solve(g)
     assert base.rounds > 3  # the run really spans several chunk boundaries
+    if mode == "fpt":
+        assert not base.found  # a miss: the whole tree was searched
 
     d = str(tmp_path / "ck")
-    r = SolverSession(config=cfg, cache=cache).solve(g, checkpoint_dir=d)
+    r = SolverSession(problem, config=cfg, cache=cache).solve(
+        g, checkpoint_dir=d
+    )
     _assert_same(r, base)
     steps = _steps(d)
     assert r.stats.checkpoints_written == len(steps) > 0
@@ -83,16 +104,18 @@ def test_solo_resume_bit_identical_at_every_boundary(tmp_path, mode_kw):
     assert superstep.PLANE_TRACES == traces_before
 
 
-def test_solve_many_resume_bit_identical_across_compaction(tmp_path):
+@pytest.mark.parametrize("problem", PROBLEMS)
+def test_solve_many_resume_bit_identical_across_compaction(tmp_path, problem):
     sizes = [(20, 1), (30, 2), (34, 3), (18, 4), (33, 5), (26, 6)]
-    gs = [erdos_renyi(n, 0.3, seed=s) for n, s in sizes]
+    gs = [erdos_renyi(n, _edge_p(problem, 0.3), seed=s) for n, s in sizes]
     cfg = SolveConfig(**CFG)
     cache = PlaneCache()
-    base = SolverSession(config=cfg, cache=cache).solve_many(gs)
+    session = SolverSession(problem, config=cfg, cache=cache)
+    base = session.solve_many(gs)
     assert base.compactions >= 1  # the batch really crosses a compaction
 
     d = str(tmp_path / "ck")
-    r = SolverSession(config=cfg, cache=cache).solve_many(gs, checkpoint_dir=d)
+    r = session.solve_many(gs, checkpoint_dir=d)
     for a, b in zip(r.results, base.results):
         _assert_same(a, b)
     steps = _steps(d)
@@ -112,21 +135,22 @@ def test_solve_many_resume_bit_identical_across_compaction(tmp_path):
     assert superstep.PLANE_TRACES == traces_before
 
 
-def test_occupied_service_restores_and_finishes_every_ticket(tmp_path):
+@pytest.mark.parametrize("problem", PROBLEMS)
+def test_occupied_service_restores_and_finishes_every_ticket(tmp_path, problem):
     sizes = [(20, 1), (30, 2), (34, 3), (18, 4), (33, 5), (26, 6), (24, 7)]
-    gs = [erdos_renyi(n, 0.3, seed=s) for n, s in sizes]
+    gs = [erdos_renyi(n, _edge_p(problem, 0.3), seed=s) for n, s in sizes]
     cfg = SolveConfig(
         num_workers=4, steps_per_round=2, chunk_rounds=1, service_lanes=3
     )
     cache = PlaneCache()
 
-    svc = SolveService("vertex_cover", cfg, cache=cache)
+    svc = SolveService(problem, cfg, cache=cache)
     tickets = [svc.submit(g) for g in gs]
     svc.drain()
     base = {t: svc.result(t) for t in tickets}
 
     # occupy the plane: live lanes AND a pending queue at checkpoint time
-    svc = SolveService("vertex_cover", cfg, cache=cache)
+    svc = SolveService(problem, cfg, cache=cache)
     tickets = [svc.submit(g) for g in gs]
     done_before = []
     for _ in range(4):

@@ -12,38 +12,46 @@ from repro.graphs.bitgraph import n_words
 from repro.graphs.generators import erdos_renyi
 from repro.problems.base import make_data
 from repro.problems.registry import get_problem
-from repro.problems.sequential import solve_sequential, verify_cover
+from repro.problems.sequential import solve_sequential
 
 VC = get_problem("vertex_cover")
+PROBLEMS = ["vertex_cover", "max_clique", "mis"]
 
 
+@pytest.mark.parametrize("problem", PROBLEMS)
 @pytest.mark.parametrize("policy", [True, False])
 @pytest.mark.parametrize("codec", ["optimized", "basic"])
-def test_matches_sequential(policy, codec):
+def test_matches_sequential(policy, codec, problem):
+    spec = get_problem(problem)
     g = erdos_renyi(40, 0.28, 0)
-    want, _, _ = solve_sequential(g)
+    want, _, _ = spec.sequential(g)
     r = E.solve(
         g, num_workers=6, steps_per_round=8,
-        policy_priority=policy, codec=codec,
+        policy_priority=policy, codec=codec, problem=problem,
     )
     assert r.best_size == want
-    assert verify_cover(g, r.best_sol)
+    assert spec.verify(g, r.best_sol)
     assert not r.overflow
 
 
-def test_lanes():
+@pytest.mark.parametrize("problem", PROBLEMS)
+def test_lanes(problem):
     g = erdos_renyi(44, 0.25, 4)
-    want, _, _ = solve_sequential(g)
-    r = E.solve(g, num_workers=4, steps_per_round=4, lanes=4)
+    want, _, _ = get_problem(problem).sequential(g)
+    r = E.solve(g, num_workers=4, steps_per_round=4, lanes=4, problem=problem)
     assert r.best_size == want
     assert not r.overflow
 
 
-def test_fpt_mode():
+@pytest.mark.parametrize("problem", PROBLEMS)
+def test_fpt_mode(problem):
+    spec = get_problem(problem)
     g = erdos_renyi(34, 0.3, 9)
-    opt, _, _ = solve_sequential(g)
-    r = E.solve(g, num_workers=4, mode="fpt", k=opt)
-    assert r.best_size != -1 and r.best_size <= opt
+    opt, _, _ = spec.sequential(g)
+    r = E.solve(g, num_workers=4, mode="fpt", k=opt, problem=problem)
+    # a decision at the optimum is met by an optimal witness, no better
+    assert r.best_size == opt
+    assert spec.verify(g, r.best_sol)
 
 
 @settings(max_examples=8, deadline=None)
@@ -75,19 +83,21 @@ def test_snapshot_restore_resize():
     assert r.best_size == want
 
 
-def test_transfer_accounting():
+@pytest.mark.parametrize("problem", PROBLEMS)
+def test_transfer_accounting(problem):
     g = erdos_renyi(40, 0.28, 0)
     W = n_words(g.n)
     rec_opt = 2 * W + 1
     rec_bas = (g.n + 2) * W + 1
+    kw = dict(num_workers=4, problem=problem)
     # gather: every transfer round moves the full P-row record table
-    r_opt = E.solve(g, num_workers=4, codec="optimized", transfer_impl="gather")
-    r_bas = E.solve(g, num_workers=4, codec="basic", transfer_impl="gather")
+    r_opt = E.solve(g, codec="optimized", transfer_impl="gather", **kw)
+    r_bas = E.solve(g, codec="basic", transfer_impl="gather", **kw)
     assert r_opt.transfer_bytes_total == 4 * rec_opt * 4 * r_opt.transfer_rounds
     assert r_bas.transfer_bytes_total == 4 * rec_bas * 4 * r_bas.transfer_rounds
     # sparse: payload == exactly the records that matched (paper: the donated
     # task is the sole payload), regardless of P
-    r_sp = E.solve(g, num_workers=4, codec="optimized", transfer_impl="sparse")
+    r_sp = E.solve(g, codec="optimized", transfer_impl="sparse", **kw)
     assert r_sp.transfer_bytes_total == 4 * rec_opt * r_sp.tasks_transferred
     assert r_sp.transfer_bytes_total < r_opt.transfer_bytes_total
     # rounds that ran no transfer move zero payload on either path
@@ -95,27 +105,31 @@ def test_transfer_accounting():
     # the paper's point: control plane is O(P) integers regardless of codec —
     # ONE packed i32 per worker by default, three with packed_status=False
     assert r_opt.control_bytes_per_round == r_bas.control_bytes_per_round == 16
-    r_unpacked = E.solve(g, num_workers=4, packed_status=False)
+    r_unpacked = E.solve(g, packed_status=False, **kw)
     assert r_unpacked.control_bytes_per_round == 48
 
 
-def test_chunked_loop_matches_per_round():
+@pytest.mark.parametrize("problem", PROBLEMS)
+def test_chunked_loop_matches_per_round(problem):
     """K supersteps per host sync must be bit-identical to per-round syncs."""
     g = erdos_renyi(40, 0.28, 0)
-    want, _, _ = solve_sequential(g)
-    r1 = E.solve(g, num_workers=6, steps_per_round=8, chunk_rounds=1)
-    rk = E.solve(g, num_workers=6, steps_per_round=8, chunk_rounds=32)
+    want, _, _ = get_problem(problem).sequential(g)
+    kw = dict(num_workers=6, steps_per_round=8, problem=problem)
+    r1 = E.solve(g, chunk_rounds=1, **kw)
+    rk = E.solve(g, chunk_rounds=32, **kw)
     assert r1.best_size == rk.best_size == want
     assert (r1.best_sol == rk.best_sol).all()
     assert r1.rounds == rk.rounds
     assert r1.nodes_expanded == rk.nodes_expanded
 
 
-def test_multi_task_donation():
+@pytest.mark.parametrize("problem", PROBLEMS)
+def test_multi_task_donation(problem):
     g = erdos_renyi(44, 0.25, 4)
-    want, _, _ = solve_sequential(g)
-    r1 = E.solve(g, num_workers=8, steps_per_round=4, donate_k=1)
-    r4 = E.solve(g, num_workers=8, steps_per_round=4, donate_k=4)
+    want, _, _ = get_problem(problem).sequential(g)
+    kw = dict(num_workers=8, steps_per_round=4, problem=problem)
+    r1 = E.solve(g, donate_k=1, **kw)
+    r4 = E.solve(g, donate_k=4, **kw)
     assert r1.best_size == r4.best_size == want
     assert not r4.overflow
     # single-task donation ships exactly one record per match...

@@ -53,12 +53,13 @@ from repro.core.encoding import (
 from repro.core.spill import FrontierSpiller
 from repro.faults import FAULT_KINDS, FaultEvent, FaultInjector, FaultPlan
 from repro.graphs.generators import erdos_renyi
-from repro.problems.sequential import solve_sequential
+from repro.problems.registry import get_problem
 
 # one warm plane cache for the whole module: property examples re-solve the
 # same shapes many times and must not recompile each time
 _CACHE = PlaneCache()
-_BASELINES: dict = {}
+_BASELINES: dict = {}  # (case, problem) -> the fault-free run
+PROBLEMS = ["vertex_cover", "max_clique", "mis"]
 
 
 def _clock():
@@ -261,19 +262,20 @@ def test_corrupt_generation_falls_back_to_older(tmp_path):
 # -- 5. crash anywhere ---------------------------------------------------------
 
 
-def _solo_case():
-    if "solo" not in _BASELINES:
+def _solo_case(problem):
+    if ("solo", problem) not in _BASELINES:
         g = erdos_renyi(30, 0.3, 5)
         cfg = SolveConfig(num_workers=4, steps_per_round=2, chunk_rounds=1)
-        sess = SolverSession("vertex_cover", config=cfg, cache=_CACHE)
-        _BASELINES["solo"] = (g, sess, sess.solve(g))
-    return _BASELINES["solo"]
+        sess = SolverSession(problem, config=cfg, cache=_CACHE)
+        _BASELINES["solo", problem] = (g, sess, sess.solve(g))
+    return _BASELINES["solo", problem]
 
 
+@pytest.mark.parametrize("problem", PROBLEMS)
 @settings(max_examples=6, deadline=None)
 @given(st.integers(0, 12))
-def test_solo_crash_at_any_boundary_is_bit_identical(boundary):
-    g, sess, base = _solo_case()
+def test_solo_crash_at_any_boundary_is_bit_identical(problem, boundary):
+    g, sess, base = _solo_case(problem)
     inj = FaultInjector(
         FaultPlan(seed=0, events=(FaultEvent("crash", at=boundary),))
     )
@@ -287,18 +289,19 @@ def test_solo_crash_at_any_boundary_is_bit_identical(boundary):
     assert inj.injected["crash"] == inj.recovered["crash"]
 
 
+@pytest.mark.parametrize("problem", PROBLEMS)
 @settings(max_examples=4, deadline=None)
 @given(st.integers(0, 10))
-def test_fpt_crash_keeps_the_witness(boundary):
-    if "fpt" not in _BASELINES:
+def test_fpt_crash_keeps_the_witness(problem, boundary):
+    if ("fpt", problem) not in _BASELINES:
         g = erdos_renyi(26, 0.3, 4)
-        k = solve_sequential(g)[0]
+        k = get_problem(problem).sequential(g)[0]
         cfg = SolveConfig(
             num_workers=4, steps_per_round=2, chunk_rounds=1, mode="fpt", k=k
         )
-        sess = SolverSession("vertex_cover", config=cfg, cache=_CACHE)
-        _BASELINES["fpt"] = (g, sess, sess.solve(g))
-    g, sess, base = _BASELINES["fpt"]
+        sess = SolverSession(problem, config=cfg, cache=_CACHE)
+        _BASELINES["fpt", problem] = (g, sess, sess.solve(g))
+    g, sess, base = _BASELINES["fpt", problem]
     inj = FaultInjector(
         FaultPlan(seed=0, events=(FaultEvent("crash", at=boundary),))
     )
@@ -307,15 +310,18 @@ def test_fpt_crash_keeps_the_witness(boundary):
     assert (np.asarray(r.best_sol) == np.asarray(base.best_sol)).all()
 
 
+@pytest.mark.parametrize("problem", PROBLEMS)
 @settings(max_examples=4, deadline=None)
 @given(st.integers(0, 8), st.integers(0, 3))
-def test_solve_many_crash_at_any_boundary_is_bit_identical(boundary, lane):
-    if "many" not in _BASELINES:
+def test_solve_many_crash_at_any_boundary_is_bit_identical(
+    problem, boundary, lane
+):
+    if ("many", problem) not in _BASELINES:
         gs = [erdos_renyi(26, 0.3, 20 + i) for i in range(2)]
         cfg = SolveConfig(num_workers=4, steps_per_round=2, chunk_rounds=1)
-        sess = SolverSession("vertex_cover", config=cfg, cache=_CACHE)
-        _BASELINES["many"] = (gs, sess, sess.solve_many(gs))
-    gs, sess, base = _BASELINES["many"]
+        sess = SolverSession(problem, config=cfg, cache=_CACHE)
+        _BASELINES["many", problem] = (gs, sess, sess.solve_many(gs))
+    gs, sess, base = _BASELINES["many", problem]
     inj = FaultInjector(
         FaultPlan(
             seed=0, events=(FaultEvent("crash", at=boundary, lane=lane),)
@@ -331,23 +337,26 @@ def test_solve_many_crash_at_any_boundary_is_bit_identical(boundary, lane):
     assert inj.injected["crash"] == inj.recovered["crash"]
 
 
+@pytest.mark.parametrize("problem", PROBLEMS)
 @settings(max_examples=4, deadline=None)
 @given(st.integers(0, 8), st.integers(0, 3))
-def test_service_crash_at_any_boundary_is_bit_identical(boundary, lane):
-    if "service" not in _BASELINES:
+def test_service_crash_at_any_boundary_is_bit_identical(
+    problem, boundary, lane
+):
+    if ("service", problem) not in _BASELINES:
         gs = [erdos_renyi(26, 0.3, 30 + i) for i in range(3)]
         cfg = SolveConfig(
             num_workers=4, steps_per_round=2, chunk_rounds=1,
             service_lanes=2,
         )
-        sess = SolverSession("vertex_cover", config=cfg, cache=_CACHE)
+        sess = SolverSession(problem, config=cfg, cache=_CACHE)
         svc = sess.serve()
         tix = [svc.submit(g) for g in gs]
         svc.drain()
-        _BASELINES["service"] = (
+        _BASELINES["service", problem] = (
             gs, sess, {i: svc.result(t) for i, t in enumerate(tix)}
         )
-    gs, sess, want = _BASELINES["service"]
+    gs, sess, want = _BASELINES["service", problem]
     inj = FaultInjector(
         FaultPlan(
             seed=0, events=(FaultEvent("crash", at=boundary, lane=lane),)
@@ -419,13 +428,23 @@ def test_pump_host_conserves_multiset_under_injected_corruption():
     assert sp.delivery_retries == inj.retries == inj.faults_injected
 
 
-def test_saturated_solve_unchanged_by_payload_corruption():
-    g = erdos_renyi(40, 0.28, 0)
+# an instance per problem whose frontier saturates capacity 16: a clique
+# search runs deep on dense graphs, an MIS search on sparse ones
+_SATURATING = {
+    "vertex_cover": (40, 0.28, 0),
+    "max_clique": (20, 0.9, 5),
+    "mis": (20, 0.1, 3),
+}
+
+
+@pytest.mark.parametrize("problem", PROBLEMS)
+def test_saturated_solve_unchanged_by_payload_corruption(problem):
+    g = erdos_renyi(*_SATURATING[problem])
     cfg = SolveConfig(
         num_workers=4, steps_per_round=2, chunk_rounds=2, capacity=16,
         frontier_spill=True,
     )
-    sess = SolverSession("vertex_cover", config=cfg, cache=_CACHE)
+    sess = SolverSession(problem, config=cfg, cache=_CACHE)
     base = sess.solve(g)
     assert base.stats.spilled_tasks > 0
     inj = FaultInjector(
@@ -448,12 +467,13 @@ def test_saturated_solve_unchanged_by_payload_corruption():
 # -- 7. quarantine, degradation, rehabilitation --------------------------------
 
 
-def test_repeated_crashes_quarantine_shed_and_still_complete():
+@pytest.mark.parametrize("problem", PROBLEMS)
+def test_repeated_crashes_quarantine_shed_and_still_complete(problem):
     gs = [erdos_renyi(28, 0.3, 50 + i) for i in range(4)]
     cfg = SolveConfig(
         num_workers=4, steps_per_round=2, chunk_rounds=1, service_lanes=2,
     )
-    sess = SolverSession("vertex_cover", config=cfg, cache=_CACHE)
+    sess = SolverSession(problem, config=cfg, cache=_CACHE)
     svc_ref = sess.serve()
     ref_tix = [svc_ref.submit(g) for g in gs]
     svc_ref.drain()
@@ -482,13 +502,14 @@ def test_repeated_crashes_quarantine_shed_and_still_complete():
     assert s["lanes_shed"] == 0
 
 
-def test_stall_watchdog_quarantines_and_replays():
+@pytest.mark.parametrize("problem", PROBLEMS)
+def test_stall_watchdog_quarantines_and_replays(problem):
     gs = [erdos_renyi(28, 0.3, 60 + i) for i in range(3)]
     cfg = SolveConfig(
         num_workers=4, steps_per_round=2, chunk_rounds=1, service_lanes=2,
         lane_stall_chunks=2,
     )
-    sess = SolverSession("vertex_cover", config=cfg, cache=_CACHE)
+    sess = SolverSession(problem, config=cfg, cache=_CACHE)
     svc_ref = sess.serve()
     ref_tix = [svc_ref.submit(g) for g in gs]
     svc_ref.drain()
@@ -543,7 +564,8 @@ def test_queued_request_times_out_with_typed_error():
     assert svc.idle()  # nothing left behind — no hung request survives
 
 
-def test_on_lane_request_times_out_with_partial_result():
+@pytest.mark.parametrize("problem", PROBLEMS)
+def test_on_lane_request_times_out_with_partial_result(problem):
     from repro.api import SolveService
 
     clk = _clock()
@@ -551,7 +573,7 @@ def test_on_lane_request_times_out_with_partial_result():
         num_workers=4, steps_per_round=2, chunk_rounds=1, service_lanes=1,
         request_timeout_s=5.0,
     )
-    svc = SolveService("vertex_cover", cfg, clock=clk, cache=_CACHE)
+    svc = SolveService(problem, cfg, clock=clk, cache=_CACHE)
     t = svc.submit(erdos_renyi(34, 0.5, 7))
     svc.step()  # on the lane, within budget
     clk.t = 10.0

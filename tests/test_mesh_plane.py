@@ -99,6 +99,8 @@ def _run_child(problem, graph, workers, lanes):
         ("vertex_cover", "gnp:44:0.15:1", 2, 1),
         ("max_clique", "gnp:48:0.3:3", 1, 4),
         ("max_clique", "gnp:48:0.3:3", 4, 1),
+        ("mis", "gnp:40:0.4:3", 1, 4),
+        ("mis", "gnp:40:0.4:3", 2, 1),
     ],
 )
 def test_mesh_plane_is_bit_identical_cached_and_counts_remote_tasks(

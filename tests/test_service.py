@@ -35,6 +35,7 @@ from repro.api.backends import config_from_legacy
 from repro.api.service import LaneScheduler, SolveRequest
 from repro.core import superstep
 from repro.graphs.generators import erdos_renyi
+from repro.problems.registry import get_problem
 from repro.problems.sequential import (
     solve_sequential,
     solve_sequential_max_clique,
@@ -43,6 +44,11 @@ from repro.problems.sequential import (
 GOLDEN = json.loads(
     (pathlib.Path(__file__).parent / "golden_vc.json").read_text()
 )
+PROBLEMS = ["vertex_cover", "max_clique", "mis"]
+
+
+def _minimizes(problem) -> bool:
+    return get_problem(problem).objective.startswith("minimize")
 
 
 def _check_golden(r, want: dict):
@@ -97,17 +103,18 @@ def test_service_fpt_bit_identical_to_golden():
     _check_golden(svc.result(t), case["result"])
 
 
-def test_service_churn_matches_solo_across_sizes():
+@pytest.mark.parametrize("problem", PROBLEMS)
+def test_service_churn_matches_solo_across_sizes(problem):
     """A stream wider than the lane count: every instance that churns
     through a reused lane matches its solo solve bit-for-bit (best, rounds,
     counters), across mixed sizes within the W bucket."""
     cfg = SolveConfig(num_workers=4, steps_per_round=8, service_lanes=2)
     sizes = [18, 26, 22, 30, 20, 24]
     gs = [erdos_renyi(n, 0.3, 200 + i) for i, n in enumerate(sizes)]
-    svc = SolveService("vertex_cover", cfg)
+    svc = SolveService(problem, cfg)
     tickets = [svc.submit(g) for g in gs]
     svc.drain()
-    sess = SolverSession(problem="vertex_cover", config=cfg)
+    sess = SolverSession(problem=problem, config=cfg)
     for t, g in zip(tickets, gs):
         r, solo = svc.result(t), sess.solve(g)
         assert r.best_size == solo.best_size
@@ -121,9 +128,10 @@ def test_service_churn_matches_solo_across_sizes():
 # -- 2. zero-retrace admission into freed lanes --------------------------------
 
 
-def test_admission_into_freed_lanes_traces_nothing():
+@pytest.mark.parametrize("problem", PROBLEMS)
+def test_admission_into_freed_lanes_traces_nothing(problem):
     cfg = SolveConfig(num_workers=4, steps_per_round=8, service_lanes=2)
-    svc = SolveService("vertex_cover", cfg)
+    svc = SolveService(problem, cfg)
     wave1 = [svc.submit(erdos_renyi(20, 0.3, s)) for s in range(2)]
     svc.drain()  # compiles the plane (first wave)
     traces0 = superstep.PLANE_TRACES
@@ -143,14 +151,16 @@ def test_admission_into_freed_lanes_traces_nothing():
 # -- 3. streaming lifecycle ----------------------------------------------------
 
 
-def test_out_of_order_completion_streams_early_finishers():
+@pytest.mark.parametrize("problem", PROBLEMS)
+def test_out_of_order_completion_streams_early_finishers(problem):
     """An easy instance submitted AFTER a hard one completes first and its
     result is poppable while the hard lane keeps solving."""
+    sequential = get_problem(problem).sequential
     cfg = SolveConfig(
         num_workers=2, steps_per_round=2, chunk_rounds=1, service_lanes=2,
         admission="fifo",
     )
-    svc = SolveService("vertex_cover", cfg)
+    svc = SolveService(problem, cfg)
     hard = svc.submit(erdos_renyi(30, 0.5, 3))
     easy = svc.submit(erdos_renyi(8, 0.3, 4))
     completed, steps = [], 0
@@ -162,9 +172,9 @@ def test_out_of_order_completion_streams_early_finishers():
     if not svc.ready(hard):  # the point: easy streamed out mid-solve
         assert svc.status()["planes"]["(1, None)"]["tickets"] == [hard]
     r_easy = svc.result(easy)
-    assert r_easy.best_size == solve_sequential(erdos_renyi(8, 0.3, 4))[0]
+    assert r_easy.best_size == sequential(erdos_renyi(8, 0.3, 4))[0]
     svc.drain()
-    assert svc.result(hard).best_size == solve_sequential(
+    assert svc.result(hard).best_size == sequential(
         erdos_renyi(30, 0.5, 3)
     )[0]
 
@@ -183,16 +193,26 @@ def test_result_before_completion_raises_keyerror():
     assert svc.ready(t) and svc.result(t).found
 
 
-def test_overflow_count_propagates_into_streamed_results():
+# an instance per problem that overruns capacity 6; a clique search only
+# runs deep enough on a dense graph
+_STARVING = {
+    "vertex_cover": (26, 0.3, 0),
+    "max_clique": (26, 0.9, 0),
+    "mis": (26, 0.3, 0),
+}
+
+
+@pytest.mark.parametrize("problem", PROBLEMS)
+def test_overflow_count_propagates_into_streamed_results(problem):
     """A capacity-starved config overflows identically through the service
     and the solo path — the live plane does not hide dropped work."""
     cfg = SolveConfig(
         num_workers=2, steps_per_round=4, capacity=6, service_lanes=2
     )
-    g = erdos_renyi(26, 0.3, 0)
-    solo = SolverSession(problem="vertex_cover", config=cfg).solve(g)
+    g = erdos_renyi(*_STARVING[problem])
+    solo = SolverSession(problem=problem, config=cfg).solve(g)
     assert solo.stats.overflow_count > 0  # the config really starves
-    svc = SolveService("vertex_cover", cfg)
+    svc = SolveService(problem, cfg)
     t = svc.submit(g)
     svc.drain()
     r = svc.result(t)
@@ -200,12 +220,13 @@ def test_overflow_count_propagates_into_streamed_results():
     assert r.stats.overflow and r.best_size == solo.best_size
 
 
-def test_deadline_evicts_with_anytime_result():
+@pytest.mark.parametrize("problem", PROBLEMS)
+def test_deadline_evicts_with_anytime_result(problem):
     cfg = SolveConfig(
         num_workers=2, steps_per_round=2, chunk_rounds=1, service_lanes=2
     )
     g = erdos_renyi(32, 0.5, 7)
-    svc = SolveService("vertex_cover", cfg)
+    svc = SolveService(problem, cfg)
     t = svc.submit(g, deadline=1)
     svc.drain()
     r = svc.result(t)
@@ -213,10 +234,13 @@ def test_deadline_evicts_with_anytime_result():
     assert r.rounds == 1  # stopped at the budget, not at optimality
     assert svc.stats()["evicted"] == 1
     # the anytime answer is a valid-but-possibly-loose bound vs full solve
-    full = SolverSession(problem="vertex_cover", config=cfg).solve(g)
-    assert r.best_size >= full.best_size
+    full = SolverSession(problem=problem, config=cfg).solve(g)
+    if _minimizes(problem):
+        assert r.best_size >= full.best_size
+    else:
+        assert r.best_size <= full.best_size
     # a finished (non-evicted) lane never reports a deadline hit
-    svc2 = SolveService("vertex_cover", cfg)
+    svc2 = SolveService(problem, cfg)
     t2 = svc2.submit(erdos_renyi(12, 0.3, 1), deadline=500)
     svc2.drain()
     assert svc2.result(t2).stats.service.deadline_hit is False
@@ -350,13 +374,15 @@ def test_tenant_cap_skips_without_starving():
         assert svc.ready(t)
 
 
-def test_fpt_per_request_k_overrides_config():
+@pytest.mark.parametrize("problem", PROBLEMS)
+def test_fpt_per_request_k_overrides_config(problem):
     g = erdos_renyi(20, 0.3, 2)
-    want, _, _ = solve_sequential(g)
+    want, _, _ = get_problem(problem).sequential(g)
     cfg = SolveConfig(num_workers=4, mode="fpt", k=want, service_lanes=2)
-    svc = SolveService("vertex_cover", cfg)
+    svc = SolveService(problem, cfg)
     t_yes = svc.submit(g)  # config k == optimum: found
-    t_no = svc.submit(g, k=want - 1)  # per-request tighter k: infeasible
+    # per-request k one past the optimum: infeasible
+    t_no = svc.submit(g, k=want - 1 if _minimizes(problem) else want + 1)
     svc.drain()
     assert svc.result(t_yes).found is True
     assert svc.result(t_no).found is False

@@ -9,6 +9,7 @@ axis.
 
 import jax.numpy as jnp
 import numpy as np
+import pytest
 from hypothesis import given, settings, strategies as st
 
 from repro.core import engine as E
@@ -22,7 +23,7 @@ from repro.problems.registry import get_problem
 from repro.problems.sequential import solve_sequential
 from repro.problems.vertex_cover import VCProblem
 
-VC = get_problem("vertex_cover")
+PROBLEMS = ["vertex_cover", "max_clique", "mis"]
 
 
 def _assert_matches_solo(graphs, batch, **solve_kw):
@@ -60,7 +61,8 @@ def test_batch_matches_singles_property(seed):
         assert b.best_size == want
 
 
-def test_mixed_word_buckets_preserve_order():
+@pytest.mark.parametrize("problem", PROBLEMS)
+def test_mixed_word_buckets_preserve_order(problem):
     """Instances with different packed widths W split into separate buckets;
     results still come back in submission order."""
     graphs = [
@@ -69,21 +71,22 @@ def test_mixed_word_buckets_preserve_order():
         erdos_renyi(36, 0.28, 2),  # W=2 (padded to 40 in its bucket)
         erdos_renyi(14, 0.3, 3),  # W=1 (padded to 20)
     ]
-    kw = dict(num_workers=4, steps_per_round=8)
+    kw = dict(num_workers=4, steps_per_round=8, problem=problem)
     batch = E.solve_many(graphs, **kw)
     assert sorted(W for W, _, _ in batch.buckets) == [1, 2]
     assert sorted(i for _, _, idxs in batch.buckets for i in idxs) == [0, 1, 2, 3]
     _assert_matches_solo(graphs, batch, **kw)
 
 
-def test_compaction_bit_identical():
+@pytest.mark.parametrize("problem", PROBLEMS)
+def test_compaction_bit_identical(problem):
     """Early-exit compaction (finished lanes dropped, batch re-packed to a
     smaller executable) must not perturb the surviving instances."""
     graphs = [erdos_renyi(12, 0.3, s) for s in range(6)] + [
         erdos_renyi(30, 0.25, 0),
         erdos_renyi(30, 0.28, 6),
     ]
-    kw = dict(num_workers=4, steps_per_round=1, chunk_rounds=1)
+    kw = dict(num_workers=4, steps_per_round=1, chunk_rounds=1, problem=problem)
     batch = E.solve_many(graphs, compact_threshold=0.5, **kw)
     assert batch.compactions > 0
     _assert_matches_solo(graphs, batch, **kw)
@@ -100,14 +103,18 @@ def test_basic_codec_buckets_by_exact_n():
     _assert_matches_solo(graphs, batch, **kw)
 
 
-def test_fpt_mode_per_instance_bounds():
+@pytest.mark.parametrize("problem", PROBLEMS)
+def test_fpt_mode_per_instance_bounds(problem):
+    spec = get_problem(problem)
     graphs = [erdos_renyi(24, 0.3, 1), erdos_renyi(20, 0.3, 2)]
-    opts = [solve_sequential(g)[0] for g in graphs]
+    opts = [spec.sequential(g)[0] for g in graphs]
     # per-instance k: first solvable at its optimum, second unsatisfiable
-    ks = [opts[0], opts[1] - 1]
-    batch = E.solve_many(graphs, num_workers=4, mode="fpt", k=ks)
-    assert batch.results[0].best_size != -1
-    assert batch.results[0].best_size <= opts[0]
+    # one past its optimum
+    past = -1 if spec.objective.startswith("minimize") else 1
+    ks = [opts[0], opts[1] + past]
+    batch = E.solve_many(graphs, num_workers=4, mode="fpt", k=ks, problem=problem)
+    # a decision at the optimum is met by an optimal witness, no better
+    assert batch.results[0].best_size == opts[0]
     assert batch.results[1].best_size == -1
     assert batch.results[1].best_sol is None
 
@@ -166,7 +173,8 @@ def _hand_built_batch(masks_spec, P=4, cap=8, W=1, n=16):
     return state, problems
 
 
-def test_donation_never_crosses_instance_axis():
+@pytest.mark.parametrize("problem", PROBLEMS)
+def test_donation_never_crosses_instance_axis(problem):
     """Instance 0 has idle workers but NO donor; instance 1 has a donor.
     The rebalance must stay inside each instance: instance 0 receives
     nothing even though instance 1's donor has spare tasks."""
@@ -178,7 +186,9 @@ def test_donation_never_crosses_instance_axis():
             [(0, 0x1, 3), (0, 0x3, 2), (0, 0x7, 1)],
         ]
     )
-    fn = build_batch_superstep_fn(VC, problems, steps_per_round=0, lanes=1)
+    fn = build_batch_superstep_fn(
+        get_problem(problem), problems, steps_per_round=0, lanes=1
+    )
     new, done = fn(state)
     assert not bool(done[0]) and not bool(done[1])
 
@@ -205,10 +215,13 @@ def test_donation_never_crosses_instance_axis():
     assert got[:, 0].tolist() == [0x7]
 
 
-def test_per_instance_quiescence():
+@pytest.mark.parametrize("problem", PROBLEMS)
+def test_per_instance_quiescence(problem):
     """An empty instance is done immediately; a live one in the same batch
     keeps its pending work — done is a per-instance vector."""
     state, problems = _hand_built_batch([[], [(0, 0x1, 0), (1, 0x3, 1)]])
-    fn = build_batch_superstep_fn(VC, problems, steps_per_round=0, lanes=1)
+    fn = build_batch_superstep_fn(
+        get_problem(problem), problems, steps_per_round=0, lanes=1
+    )
     _, done = fn(state)
     assert bool(done[0]) and not bool(done[1])

@@ -27,7 +27,7 @@ import os
 import numpy as np
 import pytest
 
-from repro.api import SolveConfig, SolveService, SolverSession
+from repro.api import PlaneCache, SolveConfig, SolveService, SolverSession
 from repro.core.encoding import make_codec
 from repro.core.spill import (
     BAND_WIDTH,
@@ -36,11 +36,30 @@ from repro.core.spill import (
     resolve_watermarks,
 )
 from repro.graphs.generators import erdos_renyi
-from repro.problems.sequential import verify_cover
+from repro.problems.registry import get_problem
 
 # a shape that saturates: n=40 VC explores ~150 nodes with hot peaks ~15
 # per worker, so capacity 16 with a one-chunk headroom of 7 spills
 _SAT = dict(num_workers=4, steps_per_round=2, chunk_rounds=2, capacity=16)
+
+PROBLEMS = ["vertex_cover", "max_clique", "mis"]
+# instances whose frontier saturates _SAT, per problem.  A clique search
+# only runs deep on dense graphs and an MIS search on sparse ones, so those
+# two take n=20 at p=0.9 and p=0.1 (the first is the solo instance).
+_SAT_GRAPHS = {
+    "vertex_cover": [(40, 0.28, 0), (40, 0.28, 1), (40, 0.28, 2)],
+    "max_clique": [(20, 0.9, 5), (20, 0.9, 3), (20, 0.9, 4)],
+    "mis": [(20, 0.1, 3), (20, 0.1, 0), (20, 0.1, 1)],
+}
+
+
+def _sat_graphs(problem):
+    return [erdos_renyi(*spec) for spec in _SAT_GRAPHS[problem]]
+
+
+# one warm plane cache for the whole module: the cases re-solve the same
+# shapes under the same configs and need not recompile each time
+_CACHE = PlaneCache()
 
 
 def _cfg(**over):
@@ -48,7 +67,7 @@ def _cfg(**over):
 
 
 def _solve(g, cfg, problem="vertex_cover"):
-    return SolverSession(problem, config=cfg).solve(g)
+    return SolverSession(problem, config=cfg, cache=_CACHE).solve(g)
 
 
 # -- 1. watermark resolution ---------------------------------------------------
@@ -106,11 +125,12 @@ def test_config_validates_watermarks_and_codec():
 # -- 2. saturation property: no drop, same optimum, deterministic --------------
 
 
-def test_solo_saturated_matches_unsaturated_and_is_deterministic():
-    g = erdos_renyi(40, 0.28, 0)
-    big = _solve(g, _cfg(capacity=None))
-    a = _solve(g, _cfg(frontier_spill=True))
-    b = _solve(g, _cfg(frontier_spill=True))
+@pytest.mark.parametrize("problem", PROBLEMS)
+def test_solo_saturated_matches_unsaturated_and_is_deterministic(problem):
+    g = _sat_graphs(problem)[0]
+    big = _solve(g, _cfg(capacity=None), problem)
+    a = _solve(g, _cfg(frontier_spill=True), problem)
+    b = _solve(g, _cfg(frontier_spill=True), problem)
 
     assert a.stats.spilled_tasks > 0  # the shape really saturates
     assert a.stats.readmitted_tasks == a.stats.spilled_tasks
@@ -119,7 +139,7 @@ def test_solo_saturated_matches_unsaturated_and_is_deterministic():
     # same optimum VALUE with a VALID witness — spill changes exploration
     # order, so the (equally optimal) witness may differ from big-capacity
     assert a.best_size == big.best_size
-    assert verify_cover(g, np.asarray(a.best_sol))
+    assert get_problem(problem).verify(g, np.asarray(a.best_sol))
     assert int(np.unpackbits(np.asarray(a.best_sol).view(np.uint8)).sum()) == a.best_size
 
     # run-to-run: everything identical, counters included
@@ -137,27 +157,33 @@ def test_solo_saturated_matches_unsaturated_and_is_deterministic():
     )
 
 
-def test_solo_fpt_saturated_matches_unsaturated():
-    g = erdos_renyi(40, 0.28, 0)
-    # feasible decision: spill must not change the witness
-    sat_big = _solve(g, _cfg(capacity=None, mode="fpt", k=29))
-    sat = _solve(g, _cfg(frontier_spill=True, mode="fpt", k=29))
+@pytest.mark.parametrize("problem", PROBLEMS)
+def test_solo_fpt_saturated_matches_unsaturated(problem):
+    g = _sat_graphs(problem)[0]
+    opt = get_problem(problem).sequential(g)[0]
+    # feasible decision at the optimum (29 for the vertex cover instance):
+    # spill must not change the witness
+    sat_big = _solve(g, _cfg(capacity=None, mode="fpt", k=opt), problem)
+    sat = _solve(g, _cfg(frontier_spill=True, mode="fpt", k=opt), problem)
     assert sat.found and sat_big.found
     assert sat.best_size == sat_big.best_size
     assert sat.stats.spilled_tasks > 0
-    # infeasible decision: the WHOLE tree must drain through the cold tier
-    # before the engine may answer "no"
-    unsat = _solve(g, _cfg(frontier_spill=True, mode="fpt", k=20))
+    # infeasible decision (vertex cover 20, the others one past their
+    # optimum): the WHOLE tree must drain through the cold tier before the
+    # engine may answer "no"
+    miss = 20 if problem == "vertex_cover" else opt + 1
+    unsat = _solve(g, _cfg(frontier_spill=True, mode="fpt", k=miss), problem)
     assert not unsat.found and not unsat.stats.overflow
 
 
-def test_solve_many_saturated_lanes_match_unsaturated():
-    gs = [erdos_renyi(40, 0.28, s) for s in range(3)] + [
-        erdos_renyi(18, 0.35, 2)
-    ]
-    big = SolverSession("vertex_cover", config=_cfg(capacity=None)).solve_many(gs)
+@pytest.mark.parametrize("problem", PROBLEMS)
+def test_solve_many_saturated_lanes_match_unsaturated(problem):
+    gs = _sat_graphs(problem) + [erdos_renyi(18, 0.35, 2)]
+    big = SolverSession(
+        problem, config=_cfg(capacity=None), cache=_CACHE
+    ).solve_many(gs)
     spl = SolverSession(
-        "vertex_cover", config=_cfg(frontier_spill=True)
+        problem, config=_cfg(frontier_spill=True), cache=_CACHE
     ).solve_many(gs)
     for a, b in zip(big.results, spl.results):
         assert b.best_size == a.best_size
@@ -166,13 +192,14 @@ def test_solve_many_saturated_lanes_match_unsaturated():
     assert sum(r.stats.spilled_tasks for r in spl.results) > 0
 
 
-def test_service_saturated_lanes_match_solve_many():
-    gs = [erdos_renyi(40, 0.28, s) for s in range(3)]
+@pytest.mark.parametrize("problem", PROBLEMS)
+def test_service_saturated_lanes_match_solve_many(problem):
+    gs = _sat_graphs(problem)
     ref = SolverSession(
-        "vertex_cover", config=_cfg(frontier_spill=True)
+        problem, config=_cfg(frontier_spill=True), cache=_CACHE
     ).solve_many(gs)
     svc = SolveService(
-        "vertex_cover", _cfg(frontier_spill=True, service_lanes=2)
+        problem, _cfg(frontier_spill=True, service_lanes=2), cache=_CACHE
     )
     tix = [svc.submit(g) for g in gs]
     svc.drain()
@@ -186,17 +213,18 @@ def test_service_saturated_lanes_match_solve_many():
 # -- 3. durability: the cold tier rides checkpoints ----------------------------
 
 
-def test_checkpoint_resume_mid_spill_is_bit_identical(tmp_path):
-    g = erdos_renyi(40, 0.28, 0)
+@pytest.mark.parametrize("problem", PROBLEMS)
+def test_checkpoint_resume_mid_spill_is_bit_identical(tmp_path, problem):
+    g = _sat_graphs(problem)[0]
     ck = os.path.join(str(tmp_path), "ck")
     cfg = _cfg(frontier_spill=True, checkpoint_dir=ck, checkpoint_every=1)
-    full = _solve(g, cfg)
+    full = _solve(g, cfg, problem)
     assert full.stats.spilled_tasks > 0
 
     # stop mid-solve (cold backlog checkpointed), then resume to the end
-    part = _solve(g, cfg.replace(max_rounds=6))
+    part = _solve(g, cfg.replace(max_rounds=6), problem)
     assert part.rounds == 6
-    res = _solve(g, cfg.replace(resume_from=ck))
+    res = _solve(g, cfg.replace(resume_from=ck), problem)
     assert res.stats.resumed_from is not None
     assert res.best_size == full.best_size
     assert (np.asarray(res.best_sol) == np.asarray(full.best_sol)).all()
@@ -204,21 +232,22 @@ def test_checkpoint_resume_mid_spill_is_bit_identical(tmp_path):
     assert res.stats.readmitted_tasks == full.stats.readmitted_tasks
 
 
-def test_service_restore_rebuilds_spillers(tmp_path):
-    gs = [erdos_renyi(40, 0.28, s) for s in range(3)]
+@pytest.mark.parametrize("problem", PROBLEMS)
+def test_service_restore_rebuilds_spillers(tmp_path, problem):
+    gs = _sat_graphs(problem)
     cfg = _cfg(frontier_spill=True, service_lanes=2)
-    ref = SolveService("vertex_cover", cfg)
+    ref = SolveService(problem, cfg, cache=_CACHE)
     tix = [ref.submit(g) for g in gs]
     ref.drain()
     want = {t: ref.result(t) for t in tix}
 
-    svc = SolveService("vertex_cover", cfg)
+    svc = SolveService(problem, cfg, cache=_CACHE)
     tix2 = [svc.submit(g) for g in gs]
     svc.step()
     svc.step()
     ck = os.path.join(str(tmp_path), "sck")
     svc.checkpoint(ck)
-    back = SolveService.restore(ck)
+    back = SolveService.restore(ck, cache=_CACHE)
     back.drain()
     for t, t2 in zip(tix, tix2):
         got = back.result(t2)
@@ -328,12 +357,13 @@ def test_basic_spill_codec_requires_graph():
     assert sp.codec.record_words == 12 * sp.codec.W + 2 * sp.codec.W + 1
 
 
-def test_basic_spill_codec_end_to_end():
-    g = erdos_renyi(40, 0.28, 0)
-    big = _solve(g, _cfg(capacity=None))
-    r = _solve(g, _cfg(frontier_spill=True, spill_codec="basic"))
+@pytest.mark.parametrize("problem", PROBLEMS)
+def test_basic_spill_codec_end_to_end(problem):
+    g = _sat_graphs(problem)[0]
+    big = _solve(g, _cfg(capacity=None), problem)
+    r = _solve(g, _cfg(frontier_spill=True, spill_codec="basic"), problem)
     assert r.best_size == big.best_size
     assert r.stats.spilled_tasks > 0 and not r.stats.overflow
     # basic records are (n+2)W+1 words: the cold tier is accordingly fatter
-    opt = _solve(g, _cfg(frontier_spill=True))
+    opt = _solve(g, _cfg(frontier_spill=True), problem)
     assert r.stats.cold_bytes_peak > opt.stats.cold_bytes_peak
